@@ -508,7 +508,7 @@ def bench_serving() -> None:
     prebuilt = (cfg, model, model.init(jax.random.key(0)))
     results = {}
     for policy in ("chunked", "disaggregated", "adaptive"):
-        m = run_online("stablelm-1.6b", policy=policy, pp=2, requests=10,
+        m = run_online("stablelm-1.6b-smoke", policy=policy, pp=2, requests=10,
                        max_batch=2, max_new_tokens=8, chunk_tokens=16,
                        arrival_rate=8.0, seed=0, verbose=False,
                        prebuilt=prebuilt)
@@ -537,7 +537,7 @@ def bench_serving() -> None:
     # the delta is the per-iteration sampling bubble the worker closes.
     ov = {}
     for overlap in (True, False):
-        m = run_online("stablelm-1.6b", policy="chunked", pp=2, requests=10,
+        m = run_online("stablelm-1.6b-smoke", policy="chunked", pp=2, requests=10,
                        max_batch=2, max_new_tokens=8, chunk_tokens=16,
                        arrival_rate=8.0, seed=0, verbose=False,
                        overlap_sampling=overlap, prebuilt=prebuilt)
@@ -1123,7 +1123,7 @@ def bench_hybrid() -> None:
     cfg = get_config("stablelm-1.6b-smoke")
     model = build_model(cfg, ShardCtx.single())
     prebuilt = (cfg, model, model.init(jax.random.key(0)))
-    m = run_online("stablelm-1.6b", policy="chunked", pp=2, requests=8,
+    m = run_online("stablelm-1.6b-smoke", policy="chunked", pp=2, requests=8,
                    max_batch=2, max_new_tokens=8, chunk_tokens=16,
                    kv_layout="paged", arrival_rate=8.0,
                    offline_requests=4, seed=0, verbose=False,
@@ -1146,7 +1146,7 @@ def bench_hybrid() -> None:
          f"offline_preemptions={m['offline_preemptions']}")
 
     # -- real engine: enlarged decode batches (disaggregated + ladder) ----
-    me = run_online("stablelm-1.6b", policy="disaggregated", pp=2,
+    me = run_online("stablelm-1.6b-smoke", policy="disaggregated", pp=2,
                     requests=4, max_batch=2, max_new_tokens=8,
                     chunk_tokens=16, kv_layout="paged", arrival_rate=8.0,
                     offline_requests=6, decode_enlarge_factor=2,
@@ -1197,7 +1197,7 @@ def bench_engine_e2e() -> None:
     from repro.launch.serve import run as serve_run
 
     for engine in ("naive", "sipipe"):
-        m = serve_run("stablelm-1.6b", engine=engine, pp=2, requests=4,
+        m = serve_run("stablelm-1.6b-smoke", engine=engine, pp=2, requests=4,
                       max_batch=2, max_new_tokens=5, n_samplers=2,
                       verbose=False)
         emit(f"engine_e2e/{engine}", 1e6 / max(m["throughput_tok_s"], 1e-9),
@@ -1235,6 +1235,9 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args, _ = ap.parse_known_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     def want(name):
         return only is None or name in only

@@ -5,7 +5,7 @@ and incremental SSE parsing — so you can see exactly what travels over
 the connection.  Start a server first:
 
     PYTHONPATH=src python -m repro.launch.serve \
-        --arch stablelm-1.6b --http --port 8000
+        --arch stablelm-1.6b-smoke --http --port 8000
 
 then:
 

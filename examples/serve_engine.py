@@ -48,7 +48,7 @@ def streaming_demo():
 def main():
     for engine in ("naive", "sipipe"):
         print(f"\n=== engine: {engine} ===")
-        m = run("stablelm-1.6b", engine=engine, pp=2, requests=6,
+        m = run("stablelm-1.6b-smoke", engine=engine, pp=2, requests=6,
                 max_batch=3, max_new_tokens=8, n_samplers=2)
         print(f"-> {m['finished']} finished, "
               f"{m['throughput_tok_s']:.1f} tok/s, "

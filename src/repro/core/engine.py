@@ -10,7 +10,8 @@ Two engines share all components:
                   final stage's critical path, synchronous prepare-then-
                   execute, structure-unaware stage transmission.
 
-On this container everything runs on one CPU device, so stage compute
+Stage i runs on device i of the host (cycled when there are fewer
+devices than stages).  With every stage on one device, stage compute
 serializes physically; the engines still exercise the full concurrency
 structure (threads, channels, FSMs) and *measure* the bubble anatomy:
 per-stage busy intervals, prep/stall times, sampler latency.  The paper's
@@ -24,7 +25,7 @@ import threading
 import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, Iterator, List, \
-    Optional, Tuple, Union
+    Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +71,7 @@ class PPStage:
     decode_fn: Callable                  # (params, cache, x_or_tokens, positions) -> (x|logits, cache)
     chunk_fn: Callable                   # (params, cache, x_or_tokens[T], positions[T], seq_idx[T], span_starts[B], last_idx[B], n_valid) -> (x|logits, cache)
     init_cache: Callable                 # (rows, s_max) -> cache tree
+    device: Optional[jax.Device] = None  # where params and cache live
 
     @property
     def is_first(self) -> bool:
@@ -81,17 +83,29 @@ class PPStage:
 
 
 def split_for_pp(model: Model, params: Any, p: int, *,
-                 paged: bool = False) -> List[PPStage]:
+                 paged: bool = False,
+                 devices: Optional[Sequence[jax.Device]] = None,
+                 ) -> List[PPStage]:
     """Partition a decoder LM into p contiguous stages (layer groups).
 
     ``paged`` builds decode/chunk stage functions that take the [B, nb]
     block table as a trailing argument and run attention *through* it
     (block-major physical cache in, dirty-slot write-back out) — the
-    paged-native execution path (docs/memory.md)."""
+    paged-native execution path (docs/memory.md).
+
+    Stage i's parameters are committed to ``devices[i]``; the default
+    spreads the stages over the local devices (stage i on device
+    i mod n), so a four-chip host runs a four-stage pipeline one stage
+    per chip and a single chip holds every stage."""
     assert set(model.stacks) == {"blocks"}, (
         "engine PP supports single-stack decoder families (dense/moe)")
     st = model.stacks["blocks"]
     assert st.n >= p, f"{st.n} groups < {p} stages"
+    if devices is None:
+        local = jax.local_devices()
+        devices = [local[i % len(local)] for i in range(p)]
+    if len(devices) != p:
+        raise ValueError(f"{len(devices)} stage devices for {p} stages")
     bounds = [round(i * st.n / p) for i in range(p + 1)]
     stages = []
     for i in range(p):
@@ -102,12 +116,15 @@ def split_for_pp(model: Model, params: Any, p: int, *,
             sp["embed"] = params["embed"]
         if i == p - 1:
             sp["lnf"], sp["head"] = params["lnf"], params["head"]
-        stages.append(_make_stage(model, i, p, (lo, hi), sp, paged=paged))
+        sp = jax.device_put(sp, devices[i])
+        stages.append(_make_stage(model, i, p, (lo, hi), sp, paged=paged,
+                                  device=devices[i]))
     return stages
 
 
 def _make_stage(model: Model, idx: int, p: int, bounds, sp, *,
-                paged: bool = False) -> PPStage:
+                paged: bool = False,
+                device: Optional[jax.Device] = None) -> PPStage:
     st = model.stacks["blocks"]
     lo, hi = bounds
     n_groups = hi - lo
@@ -186,12 +203,19 @@ def _make_stage(model: Model, idx: int, p: int, bounds, sp, *,
     else:
         decode_jit, chunk_jit = jax.jit(decode_fn), jax.jit(chunk_fn)
     return PPStage(idx, p, bounds, sp, jax.jit(prefill_fn), decode_jit,
-                   chunk_jit, init_cache)
+                   chunk_jit, init_cache, device)
 
 
 # ---------------------------------------------------------------------------
 # Engine
 # ---------------------------------------------------------------------------
+
+# Stall deadlines: how long a stage may wait for its upstream's hidden
+# state, and the driver for an iteration, while NO stage is executing.
+# Time inside a step (a cold full-width shape compiles there) is progress
+# and restarts the clock, so only a dead or wedged pipeline trips them.
+RECV_STALL_S = 60.0
+ITER_STALL_S = 120.0
 
 @dataclasses.dataclass
 class EngineConfig:
@@ -280,20 +304,27 @@ class _StageWorker:
         self.metrics = StageMetrics()
         cfg = engine.cfg
         rows = cfg.max_batch * cfg.pp_degree
-        if engine.paged:
-            # physical cache [groups, n_blocks + 1, block_size, ...] per
-            # leaf: logical slot p of a sequence lives at
-            # (block_table[p // bs], p %% bs); the extra final block is the
-            # trash block padded table entries point at (writes discarded,
-            # reads position-masked) — docs/memory.md
-            template = stage.init_cache(1, 1)
-            nb = engine.kv_manager.n_blocks + 1
-            bs = cfg.kv_block_size
-            self.cache = jax.tree.map(
-                lambda c: jnp.zeros((c.shape[0], nb, bs) + c.shape[3:],
-                                    c.dtype), template)
-        else:
-            self.cache = stage.init_cache(rows, cfg.max_seq_len)
+        # the pool lives beside the stage's parameters; host-side step
+        # inputs stay uncommitted, so jit moves them to this device
+        with jax.default_device(stage.device):
+            if engine.paged:
+                # physical cache [groups, n_blocks + 1, block_size, ...] per
+                # leaf: logical slot p of a sequence lives at
+                # (block_table[p // bs], p %% bs); the extra final block is
+                # the trash block padded table entries point at (writes
+                # discarded, reads position-masked) — docs/memory.md
+                template = stage.init_cache(1, 1)
+                nb = engine.kv_manager.n_blocks + 1
+                bs = cfg.kv_block_size
+                self.cache = jax.tree.map(
+                    lambda c: jnp.zeros((c.shape[0], nb, bs) + c.shape[3:],
+                                        c.dtype), template)
+            else:
+                self.cache = stage.init_cache(rows, cfg.max_seq_len)
+        # steps in progress on this stage (TSEM-off SiPipe runs one thread
+        # per submitted iteration, so steps of one stage can overlap)
+        self._running = 0
+        self._running_lock = threading.Lock()
         self.meta_cache = BatchMetadataCache(cfg.pp_degree)
         ch = StructureAwareChannel if cfg.sat else StructureUnawareChannel
         self.out_channel = ch(cfg.channel_round_latency_s) if not stage.is_last else None
@@ -353,7 +384,23 @@ class _StageWorker:
         self.cache = jax.tree.map(lambda c: c.at[:, dst].set(c[:, src]),
                                   self.cache)
 
+    @property
+    def executing(self) -> bool:
+        """True while a step of this stage runs — including the compile
+        of a shape it has not seen, which happens inside the jitted call.
+        The engine's stall deadlines do not count such time."""
+        return self._running > 0
+
     def _execute(self, desc: ModelInputDescriptor, bufs: Dict[str, np.ndarray]):
+        with self._running_lock:
+            self._running += 1
+        try:
+            return self._step(desc, bufs)
+        finally:
+            with self._running_lock:
+                self._running -= 1
+
+    def _step(self, desc: ModelInputDescriptor, bufs: Dict[str, np.ndarray]):
         t0 = time.monotonic()
         stage, eng = self.stage, self.engine
         if eng.paged and desc.sched.block_copies is not None:
@@ -462,7 +509,10 @@ class _StageWorker:
 class PPEngineBase:
     """Shared orchestration for both engines."""
 
-    def __init__(self, model: Model, params, cfg: EngineConfig):
+    def __init__(self, model: Model, params, cfg: EngineConfig,
+                 devices: Optional[Sequence[jax.Device]] = None):
+        """``devices``: one device per pipeline stage (default: the
+        stages spread over the local devices — see :func:`split_for_pp`)."""
         self.model = model
         self.arch: ArchConfig = model.cfg
         if cfg.kv_layout not in ("auto", "contiguous", "paged"):
@@ -555,7 +605,7 @@ class PPEngineBase:
         self.stages = [
             _StageWorker(s, self)
             for s in split_for_pp(model, params, cfg.pp_degree,
-                                  paged=self.paged)
+                                  paged=self.paged, devices=devices)
         ]
         self.bic_i = LocalRing(max(8, 2 * cfg.pp_degree), "BIC-I")
         self.bic_o = SubSlotRing(cfg.n_samplers, max(8, 2 * cfg.pp_degree))
@@ -609,10 +659,13 @@ class PPEngineBase:
             self._hcv.notify_all()
 
     def recv_hidden(self, stage: int, iteration: int):
-        deadline = time.monotonic() + 60
+        upstream = self.stages[stage - 1]
+        deadline = time.monotonic() + RECV_STALL_S
         with self._hcv:
             while (stage, iteration) not in self._hidden:
-                if time.monotonic() > deadline:
+                if upstream.executing:      # computing or compiling
+                    deadline = time.monotonic() + RECV_STALL_S
+                elif time.monotonic() > deadline:
                     raise TimeoutError(f"hidden for stage {stage} iter {iteration}")
                 self._hcv.wait(1.0)
             ch = self._hidden.pop((stage, iteration))
@@ -1138,11 +1191,14 @@ class PPEngineBase:
         raise NotImplementedError
 
     def _await_iteration(self, sched: SchedulingOutput):
-        deadline = time.monotonic() + 120
+        deadline = time.monotonic() + ITER_STALL_S
         while sched.iteration not in self.iter_done_t:
             if self.sampling_worker is not None:
                 self.sampling_worker.check()   # surface sampler crashes
-            if time.monotonic() > deadline:
+            now = time.monotonic()
+            if any(w.executing for w in self.stages):
+                deadline = now + ITER_STALL_S
+            elif now > deadline:
                 raise TimeoutError(
                     f"iteration {sched.iteration} never completed")
             time.sleep(0.0005)
@@ -1151,15 +1207,10 @@ class PPEngineBase:
         """Total jit executables across the stage step functions — the
         compile count benchmarks report (each distinct (batch, width,
         table-bucket) shape is one entry; bucket capping bounds it)."""
-        total = 0
-        for w in self.stages:
+        return {"jit_executables": sum(
+            fn._cache_size() for w in self.stages
             for fn in (w.stage.prefill_fn, w.stage.decode_fn,
-                       w.stage.chunk_fn):
-                try:
-                    total += fn._cache_size()
-                except Exception:          # API moved; report what we can
-                    pass
-        return {"jit_executables": total}
+                       w.stage.chunk_fn))}
 
     def load(self) -> Dict[str, int]:
         """Cheap load snapshot for routing decisions (serving/router.py):
@@ -1294,11 +1345,12 @@ class NaivePPEngine(PPEngineBase):
     final stage performs sampling *inside* its critical path (overlapped
     sampling is forced off — it's the SiPipe technique being ablated)."""
 
-    def __init__(self, model: Model, params, cfg: EngineConfig):
+    def __init__(self, model: Model, params, cfg: EngineConfig,
+                 devices: Optional[Sequence[jax.Device]] = None):
         cfg = dataclasses.replace(cfg, tsem=False, sat=False,
                                   cpu_sampling=False,
                                   overlap_sampling=False)
-        super().__init__(model, params, cfg)
+        super().__init__(model, params, cfg, devices)
 
     def _submit(self, sched: SchedulingOutput):
         for w in self.stages:

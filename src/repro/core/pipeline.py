@@ -83,10 +83,6 @@ def pp_decode_round(model: Model, plan: PPPlan) -> Callable:
     sub = dataclasses.replace(st, n=plan.groups_per_stage)
     d = model.cfg.d_model
     perm = [(i, (i + 1) % p) for i in range(p)]
-    # jax<0.6 has no partial-auto shard_map; run the region fully manual
-    # and neutralize in-region sharding constraints (they only *guide*
-    # GSPMD placement — the math is identical without them)
-    legacy_manual = not hasattr(jax, "shard_map")
 
     def stage_body(stage_l, blocks_l, caches_l, inflight_l, embeds, positions):
         # stage_l [1]; blocks_l [1, gps, ...]; caches_l [1, p, gps, ...];
@@ -104,10 +100,6 @@ def pp_decode_round(model: Model, plan: PPPlan) -> Callable:
             x_in = jnp.where(s == 0, embeds[m].astype(x.dtype), x)
             cache_m = jax.tree.map(lambda c: c[m], caches)
             ctx = model.make_ctx("decode", positions[m])
-            if legacy_manual:
-                from repro.models.common import ShardCtx
-
-                ctx = dataclasses.replace(ctx, shard=ShardCtx.single())
             x_out, cache_m = run_stack(sub, blocks_l, x_in, ctx,
                                        cache_stacked=cache_m, remat=False)
             caches = jax.tree.map(
@@ -122,18 +114,10 @@ def pp_decode_round(model: Model, plan: PPPlan) -> Callable:
         pack = lambda t: jax.tree.map(lambda a: a[None], t)
         return pack(caches_l), x_fin[None], emits[None]
 
-    specs = dict(
+    smapped = jax.shard_map(
+        stage_body, mesh=plan.mesh, axis_names={"pipe"}, check_vma=False,
         in_specs=(P("pipe"), P("pipe"), P("pipe"), P("pipe"), P(None), P(None)),
-        out_specs=(P("pipe"), P("pipe"), P("pipe")),
-    )
-    if hasattr(jax, "shard_map"):
-        smapped = jax.shard_map(stage_body, mesh=plan.mesh,
-                                axis_names={"pipe"}, check_vma=False, **specs)
-    else:  # jax<0.6 compat: experimental namespace, fully-manual region
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        smapped = _shard_map(stage_body, mesh=plan.mesh, check_rep=False,
-                             **specs)
+        out_specs=(P("pipe"), P("pipe"), P("pipe")))
 
     def step(params, caches, inflight, tokens, positions):
         # embed all p microbatches under plain GSPMD (vocab-sharded gather)

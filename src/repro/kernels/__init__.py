@@ -3,12 +3,9 @@
 Each kernel follows the <name>.py (pl.pallas_call + BlockSpec) / ops.py
 (jit'd wrappers) / ref.py (pure-jnp oracle) convention; tests sweep
 shapes/dtypes and assert_allclose against the oracles in interpret mode.
+
+The package re-exports nothing: a function bound under a submodule's
+name (``span_attention``) would shadow that submodule for
+``from repro.kernels import span_attention``.  Import from the
+submodules.
 """
-from repro.kernels.flash_attention import flash_attention  # noqa: F401
-from repro.kernels.decode_attention import decode_attention  # noqa: F401
-from repro.kernels.span_attention import (  # noqa: F401
-    span_attention,
-    span_attention_quant,
-    span_attention_rolling,
-)
-from repro.kernels.swiglu import swiglu  # noqa: F401

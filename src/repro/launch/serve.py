@@ -1,15 +1,19 @@
-"""Serving driver: the SiPipe engine end-to-end on a real (reduced) model
-with a ShareGPT-shaped workload.
+"""Serving driver: the SiPipe engine end-to-end on a model with random
+weights drawn from ``--seed`` and a ShareGPT-shaped workload.
+
+``--arch`` is built as named: ``stablelm-1.6b`` at its published widths
+(for a TPU), ``stablelm-1.6b-smoke`` as the reduced same-family config
+(CPU-sized).
 
 Offline batch (enqueue everything, blocking run):
 
-  PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b \
+  PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b-smoke \
       --engine sipipe --pp 2 --requests 8
 
 Online continuous serving (Poisson arrivals replayed through the
 step-driven request API, docs/serving.md):
 
-  PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b \
+  PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b-smoke \
       --online --arrival-rate 8 --policy chunked --chunk-tokens 16
 """
 from __future__ import annotations
@@ -25,10 +29,19 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.engine import EngineConfig, NaivePPEngine, SiPipeEngine
 from repro.core.sampling_params import SamplingParams
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ModelOptions, ShardCtx, build_model
 from repro.runtime.data import ShareGPTLike
 
 POLICY_CHOICES = ["auto", "monolithic", "chunked", "disaggregated", "adaptive"]
+
+
+def build_random_model(arch: str, seed: int = 0):
+    """(cfg, model, params) for ``arch`` exactly as named, with random
+    weights drawn from ``seed`` (no checkpoint is needed to serve)."""
+    cfg = get_config(arch)
+    model = build_model(cfg, ShardCtx.single(), ModelOptions())
+    return cfg, model, model.init(jax.random.key(seed))
 
 
 def _build_engine(arch: str, *, engine: str, pp: int, max_batch: int,
@@ -43,10 +56,7 @@ def _build_engine(arch: str, *, engine: str, pp: int, max_batch: int,
     if prebuilt is not None:
         cfg, model, params = prebuilt
     else:
-        cfg = get_config(arch + "-smoke" if not arch.endswith("-smoke")
-                         else arch)
-        model = build_model(cfg, ShardCtx.single(), ModelOptions())
-        params = model.init(jax.random.key(0))
+        cfg, model, params = build_random_model(arch, seed)
     ecfg = EngineConfig(pp_degree=pp, max_batch=max_batch,
                         max_seq_len=max_seq_len, n_samplers=n_samplers,
                         prefill_chunk_tokens=chunk_tokens or None,
@@ -224,15 +234,8 @@ def build_http_server(arch: str, *, engine: str = "sipipe", replicas: int = 1,
     OpenAI-style completions server (docs/http.md)."""
     from repro.serving import CompletionServer, EngineReplica, Router
 
-    if prebuilt is None:
-        cfg = get_config(arch + "-smoke" if not arch.endswith("-smoke")
-                         else arch)
-        model = build_model(cfg, ShardCtx.single(), ModelOptions())
-        params = model.init(jax.random.key(0))
-        prebuilt_full = (cfg, model, params)
-    else:
-        prebuilt_full = prebuilt
-        cfg = prebuilt_full[0]
+    prebuilt_full = prebuilt or build_random_model(arch, seed)
+    cfg = prebuilt_full[0]
     reps = []
     for i in range(replicas):
         _, eng = _build_engine(arch, engine=engine, pp=pp,
@@ -415,7 +418,11 @@ def _print_metrics(m: dict):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="stablelm-1.6b")
+    ap.add_argument("--arch", default="stablelm-1.6b-smoke",
+                    help="model config as named: e.g. stablelm-1.6b at "
+                         "published widths, stablelm-1.6b-smoke reduced")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and the workload")
     ap.add_argument("--engine", default="sipipe", choices=["sipipe", "naive"])
     ap.add_argument("--pp", type=int, default=2)
     ap.add_argument("--requests", type=int, default=8)
@@ -491,6 +498,7 @@ def main():
                          "enlargement cap for offline work, pow2 rungs "
                          "up to max_batch * factor (docs/hybrid.md)")
     args = ap.parse_args()
+    enable_compile_cache()
     common = dict(engine=args.engine, pp=args.pp, requests=args.requests,
                   max_batch=args.max_batch, max_new_tokens=args.max_new_tokens,
                   n_samplers=args.samplers, chunk_tokens=args.chunk_tokens,
@@ -498,7 +506,8 @@ def main():
                   tpot_slo_ms=args.tpot_slo_ms, kv_layout=args.kv_layout,
                   block_size=args.block_size, kv_blocks=args.kv_blocks,
                   prefix_caching=not args.no_prefix_caching,
-                  decode_enlarge_factor=args.decode_enlarge_factor)
+                  decode_enlarge_factor=args.decode_enlarge_factor,
+                  seed=args.seed)
     if args.http:
         raise SystemExit(run_http(
             args.arch, port=args.port, replicas=args.replicas,
@@ -507,7 +516,7 @@ def main():
             chunk_tokens=args.chunk_tokens, policy=args.policy,
             kv_layout=args.kv_layout, block_size=args.block_size,
             kv_blocks=args.kv_blocks, max_queue=args.max_queue,
-            max_active=args.max_active))
+            max_active=args.max_active, seed=args.seed))
     if args.online:
         run_online(args.arch, arrival_rate=args.arrival_rate,
                    abort_every=args.abort_every,

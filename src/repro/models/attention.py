@@ -16,11 +16,12 @@ Outputs are [B, Sq, Hq*hd] / [B, Hq*hd].
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import span_attention as ksa
 
 NEG_INF = -1e30
 
@@ -1051,16 +1052,11 @@ def paged_span_attention_rolling_quant_native(
 
 
 def use_pallas_paged() -> bool:
-    """Backend choice for the paged execution path: the Pallas kernels
-    (:mod:`repro.kernels.span_attention` paged twins) compile natively on
-    TPU; everywhere else interpret-mode Pallas is orders of magnitude too
-    slow for a hot path, so the bit-exact jnp natives above run instead.
-    ``REPRO_PAGED_KERNELS=pallas|native`` overrides the autodetection."""
-    mode = os.environ.get("REPRO_PAGED_KERNELS", "auto")
-    if mode == "pallas":
-        return True
-    if mode in ("native", "jnp"):
-        return False
+    """Backend choice for the paged execution path, decided by the
+    platform alone: the Pallas kernels (:mod:`repro.kernels.span_attention`
+    paged twins) compile natively on TPU; everywhere else interpret-mode
+    Pallas is orders of magnitude too slow for a hot path, so the
+    bit-exact jnp natives above run instead."""
     return jax.default_backend() == "tpu"
 
 
@@ -1069,7 +1065,6 @@ def paged_span_attention_exec(q, k_cache, v_cache, block_tables, positions,
     """Dispatch :func:`paged_span_attention` semantics to the execution
     backend (Pallas kernel on TPU, jnp native elsewhere)."""
     if use_pallas_paged():
-        from repro.kernels import span_attention as ksa
         return ksa.paged_span_attention(
             q, k_cache, v_cache, positions, seq_idx, block_tables,
             window=window, interpret=False)
@@ -1081,7 +1076,6 @@ def paged_span_attention_exec(q, k_cache, v_cache, block_tables, positions,
 def paged_span_attention_quant_exec(q, k8, ks, v8, vs, block_tables,
                                     positions, seq_idx, *, kv_block=512):
     if use_pallas_paged():
-        from repro.kernels import span_attention as ksa
         return ksa.paged_span_attention_quant(
             q, k8, ks, v8, vs, positions, seq_idx, block_tables,
             interpret=False)
@@ -1090,15 +1084,18 @@ def paged_span_attention_quant_exec(q, k8, ks, v8, vs, block_tables,
         kv_block=kv_block)
 
 
+# The rolling twins read ``n_valid`` through scalar prefetch as a [1]
+# vector (``nv_ref[0]``); the engine hands the stage a scalar, which the
+# TPU compiler rejects as an index into a rank-0 ref.
 def paged_span_attention_rolling_exec(q, k_cache, v_cache, k_span, v_span,
                                       block_tables, positions, seq_idx,
                                       offsets, n_valid, *, window,
                                       kv_block=512):
     if use_pallas_paged():
-        from repro.kernels import span_attention as ksa
         return ksa.paged_span_attention_rolling(
             q, k_cache, v_cache, k_span, v_span, positions, seq_idx,
-            offsets, n_valid, block_tables, window=window, interpret=False)
+            offsets, jnp.reshape(n_valid, (1,)), block_tables,
+            window=window, interpret=False)
     return paged_span_attention_rolling_native(
         q, k_cache, v_cache, k_span, v_span, block_tables, positions,
         seq_idx, offsets, n_valid, window=window, kv_block=kv_block)
@@ -1109,10 +1106,10 @@ def paged_span_attention_rolling_quant_exec(q, k8, ks, v8, vs, k_span,
                                             seq_idx, offsets, n_valid, *,
                                             window, kv_block=512):
     if use_pallas_paged():
-        from repro.kernels import span_attention as ksa
         return ksa.paged_span_attention_rolling_quant(
             q, k8, ks, v8, vs, k_span, v_span, positions, seq_idx,
-            offsets, n_valid, block_tables, window=window, interpret=False)
+            offsets, jnp.reshape(n_valid, (1,)), block_tables,
+            window=window, interpret=False)
     return paged_span_attention_rolling_quant_native(
         q, k8, ks, v8, vs, k_span, v_span, block_tables, positions,
         seq_idx, offsets, n_valid, window=window, kv_block=kv_block)

@@ -25,14 +25,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # canonical location moved across jax versions
-    from jax import shard_map as _shard_map_mod  # type: ignore
-
-    shard_map = _shard_map_mod  # jax>=0.7 exposes jax.shard_map directly
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from repro.configs.base import MoEConfig
 from repro.models.common import ParamSpec, ShardCtx

@@ -1,6 +1,10 @@
 """End-to-end engine tests: completion, greedy correctness vs. the
 non-pipelined reference, metadata reuse, SAT/TSEM toggles."""
 import dataclasses
+import os
+import subprocess
+import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -141,3 +145,94 @@ def test_pp4_deeper_pipeline(model_and_params):
     eng2.add_request(prompt, SamplingParams(greedy=True, max_new_tokens=4))
     done = eng2.run()
     assert [s.output_ids for s in done] == want
+
+
+def test_stall_deadlines_skip_running_steps(model_and_params, monkeypatch):
+    """A stage step that outlasts the stall deadlines (a cold full-width
+    shape compiling inside the jitted call) is progress, not a stall:
+    neither the downstream stage's receive nor the driver's wait for the
+    iteration may time out while it runs."""
+    import repro.core.engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "RECV_STALL_S", 0.2)
+    monkeypatch.setattr(engine_mod, "ITER_STALL_S", 0.2)
+    cfg, model, params = model_and_params
+    eng = SiPipeEngine(model, params, EngineConfig(
+        pp_degree=2, max_batch=1, max_seq_len=64, prefill_chunk_tokens=8))
+    stage = eng.stages[0].stage
+
+    def slow_first_call(fn):
+        calls = []
+
+        def wrapped(*args):
+            if not calls:
+                calls.append(1)
+                time.sleep(1.5)
+            return fn(*args)
+        return wrapped
+
+    stage.chunk_fn = slow_first_call(stage.chunk_fn)
+    stage.decode_fn = slow_first_call(stage.decode_fn)
+    eng.add_request([5, 9, 13], SamplingParams(greedy=True, max_new_tokens=3))
+    done = eng.run()
+    assert [len(s.output_ids) for s in done] == [3]
+
+
+PLACEMENT_SCRIPT = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, os.environ["REPRO_SRC"])
+import jax, numpy as np
+from repro.configs import get_config
+from repro.core.engine import EngineConfig, SiPipeEngine
+from repro.core.sampling_params import SamplingParams
+from repro.models import build_model
+
+devs = jax.devices()
+assert len(devs) == 4, devs
+cfg = get_config("stablelm-1.6b-smoke")
+model = build_model(cfg)
+params = model.init(jax.random.key(0))
+rng = np.random.default_rng(0)
+prompts = [rng.integers(2, cfg.vocab_size, size=n).tolist()
+           for n in (11, 5, 19)]
+
+
+def placement(eng):
+    return [({d for x in jax.tree.leaves(w.stage.params) for d in x.devices()},
+             {d for x in jax.tree.leaves(w.cache) for d in x.devices()})
+            for w in eng.stages]
+
+
+def serve(devices):
+    eng = SiPipeEngine(model, params, EngineConfig(
+        pp_degree=4, max_batch=2, max_seq_len=64, prefill_chunk_tokens=8,
+        kv_block_size=8), devices=devices)
+    before = placement(eng)
+    for p in prompts:
+        eng.add_request(p, SamplingParams(greedy=True, max_new_tokens=6))
+    done = sorted(eng.run(), key=lambda s: s.seq_id)
+    assert placement(eng) == before
+    return [s.output_ids for s in done], before
+
+
+spread, where = serve(None)
+assert where == [({d}, {d}) for d in devs], where
+one, where_one = serve([devs[0]] * 4)
+assert where_one == [({devs[0]}, {devs[0]})] * 4, where_one
+assert spread == one, (spread, one)
+assert all(len(t) == 6 for t in spread), spread
+print("PLACEMENT_OK")
+"""
+
+
+def test_stages_placed_one_per_device():
+    """With four devices, stage i's parameters and KV pool live on device
+    i (the default spread) and greedy tokens are identical to the same
+    pp=4 engine with every stage on device 0.  Runs in a child process:
+    the virtual device count is fixed when the backend starts."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", REPRO_SRC=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, "-c", PLACEMENT_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "PLACEMENT_OK" in out.stdout, out.stdout + out.stderr

@@ -9,7 +9,7 @@ import pytest
 def test_serve_driver_end_to_end():
     from repro.launch.serve import run
 
-    m = run("stablelm-1.6b", engine="sipipe", pp=2, requests=4, max_batch=2,
+    m = run("stablelm-1.6b-smoke", engine="sipipe", pp=2, requests=4, max_batch=2,
             max_new_tokens=4, n_samplers=2, verbose=False)
     assert m["finished"] == 4
     assert m["tokens"] == 16
