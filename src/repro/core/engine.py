@@ -14,9 +14,11 @@ Stage i runs on device i of the host (cycled when there are fewer
 devices than stages).  With every stage on one device, stage compute
 serializes physically; the engines still exercise the full concurrency
 structure (threads, channels, FSMs) and *measure* the bubble anatomy:
-per-stage busy intervals, prep/stall times, sampler latency.  The paper's
-H100-scale headline numbers are reproduced by the calibrated discrete-
-event simulator in benchmarks/pp_sim.py, fed with latencies measured here.
+per-stage input, upstream and copy waits, the driver's token-gate wait
+(running sums in ``metrics()``) and ``sipipe/`` profiler spans around
+each (``repro.core.tracing``).  The paper's H100-scale headline numbers
+are reproduced by the calibrated discrete-event simulator in
+benchmarks/pp_sim.py, fed with latencies measured here.
 """
 from __future__ import annotations
 
@@ -46,6 +48,7 @@ from repro.core.sampling_params import SamplingParams
 from repro.core.sat import StructureAwareChannel, StructureUnawareChannel
 from repro.core.scheduler import Scheduler, SchedulingOutput
 from repro.core.sequence import SeqStatus, Sequence, SequenceCache
+from repro.core.tracing import span
 from repro.core.tsem import (
     BatchMetadataCache,
     ModelInputDescriptor,
@@ -193,6 +196,10 @@ def _make_stage(model: Model, idx: int, p: int, bounds, sp, *,
             dataclasses.replace(sub, n=n_groups), rows, s_max)
         return stacked.zeros_cache(abstract)
 
+    # each stage's programs carry its index, so a device trace splits
+    # their time by stage (``jit_decode_fn_stage1``)
+    for fn in (prefill_fn, decode_fn, chunk_fn):
+        fn.__name__ = f"{fn.__name__}_stage{idx}"
     if paged:
         # the paged engine owns exactly one reference to the physical
         # cache and replaces it with the step's output, so the input
@@ -287,12 +294,8 @@ class EngineConfig:
     seed: int = 0
 
 
-@dataclasses.dataclass
-class StageMetrics:
-    busy: List[Tuple[float, float]] = dataclasses.field(default_factory=list)
-    prep_s: float = 0.0
-    exec_s: float = 0.0
-    sample_s: float = 0.0
+STEP_KINDS = ("decode", "chunk")
+STEP_COUNTS = ("steps", "tokens", "ctx_tokens", "rows_ctx_tokens", "sampled")
 
 
 class _StageWorker:
@@ -301,7 +304,13 @@ class _StageWorker:
     def __init__(self, stage: PPStage, engine: "PPEngineBase"):
         self.stage = stage
         self.engine = engine
-        self.metrics = StageMetrics()
+        # running sums over the engine's life, never per-step records
+        self.recv_wait_s = 0.0
+        self.d2h_s = 0.0
+        self.step_counts = {k: dict.fromkeys(STEP_COUNTS, 0)
+                            for k in STEP_KINDS}
+        self._spans = {n: f"stage{stage.index}/{n}" for n in (
+            "recv", "h2d", "launch", "device", "d2h", "send", "emit")}
         cfg = engine.cfg
         rows = cfg.max_batch * cfg.pp_degree
         # the pool lives beside the stage's parameters; host-side step
@@ -322,7 +331,8 @@ class _StageWorker:
             else:
                 self.cache = stage.init_cache(rows, cfg.max_seq_len)
         # steps in progress on this stage (TSEM-off SiPipe runs one thread
-        # per submitted iteration, so steps of one stage can overlap)
+        # per submitted iteration, so steps of one stage can overlap); the
+        # lock guards the running sums above too
         self._running = 0
         self._running_lock = threading.Lock()
         self.meta_cache = BatchMetadataCache(cfg.pp_degree)
@@ -401,61 +411,106 @@ class _StageWorker:
                 self._running -= 1
 
     def _step(self, desc: ModelInputDescriptor, bufs: Dict[str, np.ndarray]):
+        stage, eng, names = self.stage, self.engine, self._spans
+        recv_s = 0.0
+        if not stage.is_first:
+            t0 = time.monotonic()
+            with span(names["recv"]):
+                x_in = eng.recv_hidden(stage.index, desc.iteration)
+            recv_s = time.monotonic() - t0
+        with span(names["h2d"]):
+            if eng.paged and desc.sched.block_copies is not None:
+                self.apply_copies(desc.sched.block_copies)
+            if stage.is_first:
+                x_in = jnp.asarray(bufs["pack_tokens"] if desc.width > 1
+                                   else bufs["tokens"])
+            if desc.width > 1:
+                fn = stage.chunk_fn
+                args = (jnp.asarray(bufs["pack_positions"]),
+                        jnp.asarray(bufs["pack_seq"]),
+                        jnp.asarray(bufs["positions"]),
+                        jnp.asarray(bufs["last_index"]),
+                        jnp.asarray(bufs["n_valid"])[0])
+            else:
+                fn = stage.decode_fn
+                args = (jnp.asarray(bufs["positions"]),)
+            if eng.paged:
+                # paged-native path: the physical block-major cache and
+                # the [B, nb] table go straight into the jitted stage —
+                # attention reads K/V *through* the table (on TPU, inside
+                # the paged span-attention kernels' scalar-prefetched
+                # BlockSpecs; no materialized [B, nb * bs] view anywhere)
+                # and the returned cache differs in exactly the slots this
+                # iteration's tokens dirtied.  The input cache buffer is
+                # donated (one owner).
+                cache = self.cache
+                args += (jnp.asarray(bufs["block_tables"]),)
+            else:
+                rows = jnp.asarray(bufs["rows"])
+                cache = jax.tree.map(lambda c: c[:, rows], self.cache)
+        with span(names["launch"]):
+            out, new_cache = fn(stage.params, cache, x_in, *args)
+            if eng.paged:
+                self.cache = new_cache
+            else:
+                self.cache = jax.tree.map(lambda c, n: c.at[:, rows].set(n),
+                                          self.cache, new_cache)
+        with span(names["device"]):
+            out = jax.block_until_ready(out)
         t0 = time.monotonic()
-        stage, eng = self.stage, self.engine
-        if eng.paged and desc.sched.block_copies is not None:
-            self.apply_copies(desc.sched.block_copies)
-        x_in = ((jnp.asarray(bufs["pack_tokens"]) if desc.width > 1
-                 else jnp.asarray(bufs["tokens"])) if stage.is_first
-                else eng.recv_hidden(stage.index, desc.iteration))
-        if eng.paged:
-            # paged-native path: the physical block-major cache and the
-            # [B, nb] table go straight into the jitted stage — attention
-            # reads K/V *through* the table (on TPU, inside the paged
-            # span-attention kernels' scalar-prefetched BlockSpecs; no
-            # materialized [B, nb * bs] view anywhere) and the returned
-            # cache differs in exactly the slots this iteration's tokens
-            # dirtied.  The input cache buffer is donated (one owner).
-            tables = jnp.asarray(bufs["block_tables"])
-            if desc.width > 1:
-                out, new_cache = stage.chunk_fn(
-                    stage.params, self.cache, x_in,
-                    jnp.asarray(bufs["pack_positions"]),
-                    jnp.asarray(bufs["pack_seq"]),
-                    jnp.asarray(bufs["positions"]),
-                    jnp.asarray(bufs["last_index"]),
-                    jnp.asarray(bufs["n_valid"])[0],
-                    tables)
-            else:
-                out, new_cache = stage.decode_fn(
-                    stage.params, self.cache, x_in,
-                    jnp.asarray(bufs["positions"]), tables)
-            self.cache = new_cache
-        else:
-            rows = jnp.asarray(bufs["rows"])
-            cache_rows = jax.tree.map(lambda c: c[:, rows], self.cache)
-            if desc.width > 1:
-                out, new_cache = stage.chunk_fn(
-                    stage.params, cache_rows, x_in,
-                    jnp.asarray(bufs["pack_positions"]),
-                    jnp.asarray(bufs["pack_seq"]),
-                    jnp.asarray(bufs["positions"]),
-                    jnp.asarray(bufs["last_index"]),
-                    jnp.asarray(bufs["n_valid"])[0])
-            else:
-                out, new_cache = stage.decode_fn(
-                    stage.params, cache_rows, x_in,
-                    jnp.asarray(bufs["positions"]))
-            self.cache = jax.tree.map(lambda c, n: c.at[:, rows].set(n),
-                                      self.cache, new_cache)
-        out = jax.block_until_ready(out)
-        self.metrics.busy.append((t0, time.monotonic()))
+        with span(names["d2h"]):
+            out = np.asarray(out, np.float32)
+        d2h_s = time.monotonic() - t0
         if stage.is_last:
-            eng.emit_logits(desc, np.asarray(out, np.float32))
+            with span(names["emit"]):
+                eng.emit_logits(desc, out)
         else:
-            eng.send_hidden(stage.index, desc.iteration,
-                            np.asarray(out, np.float32))
+            with span(names["send"]):
+                eng.send_hidden(stage.index, desc.iteration, out)
+        # after the hand-off, off the downstream's path
+        kind, work = self._step_work(desc, bufs)
+        with self._running_lock:   # TSEM-off steps of a stage overlap
+            self.recv_wait_s += recv_s
+            self.d2h_s += d2h_s
+            c = self.step_counts[kind]
+            for k, v in work.items():
+                c[k] += v
         return True
+
+    @staticmethod
+    def _step_work(desc: ModelInputDescriptor, bufs: Dict[str, np.ndarray]):
+        """A step's kind and its :data:`STEP_COUNTS`: one step, its valid
+        tokens, the context they attend (each token's position + 1), the
+        rows' contexts (each row's furthest position + 1) and the rows
+        sampled."""
+        if desc.width > 1:
+            kind = "chunk"
+            n = int(bufs["n_valid"][0])
+            pos = bufs["pack_positions"][:n].astype(np.int64)
+            ctx = np.zeros(desc.batch, np.int64)
+            np.maximum.at(ctx, bufs["pack_seq"][:n], pos + 1)
+            rows_ctx = int(ctx.sum())
+        else:
+            kind = "decode"
+            n = desc.batch
+            pos = bufs["positions"][:n].astype(np.int64)
+            rows_ctx = int(pos.sum()) + n
+        return kind, {"steps": 1, "tokens": n,
+                      "ctx_tokens": int(pos.sum()) + n,
+                      "rows_ctx_tokens": rows_ctx,
+                      "sampled": len(desc.sched.sample_indices())}
+
+    def counters(self) -> Dict[str, float]:
+        """The stage's running sums, flat: waits and copies in seconds,
+        then ``<kind>_<count>`` per step kind."""
+        with self._running_lock:
+            out = {"input_wait_s": self.executor.stall_time,
+                   "recv_wait_s": self.recv_wait_s,
+                   "d2h_s": self.d2h_s}
+            for kind, c in self.step_counts.items():
+                for k, v in c.items():
+                    out[f"{kind}_{k}"] = v
+        return out
 
     def run_prefill(self, seq_batch: List[Sequence], x_or_tokens, pos0: int,
                     rows: np.ndarray, last_idx: np.ndarray,
@@ -465,7 +520,6 @@ class _StageWorker:
         contiguous rows)."""
         stage = self.stage
         eng = self.engine
-        t0 = time.monotonic()
         out, cache = stage.prefill_fn(stage.params, x_or_tokens, pos0,
                                       jnp.asarray(last_idx))
         if eng.paged:
@@ -496,14 +550,11 @@ class _StageWorker:
                 return c_all.at[:, rows, :sp].set(c_new)
         self.cache = jax.tree.map(write, self.cache, cache)
         out = jax.block_until_ready(out)
-        self.metrics.busy.append((t0, time.monotonic()))
         return np.asarray(out, np.float32)
 
     def stop(self):
         if isinstance(self.executor, TokenSafeExecutor):
             self.executor.stop()
-        self.metrics.prep_s = self.executor.prep_time
-        self.metrics.exec_s = self.executor.exec_time
 
 
 class PPEngineBase:
@@ -621,6 +672,7 @@ class PPEngineBase:
             for i in range(cfg.n_samplers)
         ]
         self.sample_time = 0.0
+        self.gate_wait_s = 0.0          # driver waiting on sampled tokens
         # SiPipe overlapped CPU sampling: the last stage hands logits to
         # this FIFO worker and launches the next iteration immediately;
         # the worker mutates sampler state in submission (= iteration)
@@ -691,17 +743,20 @@ class PPEngineBase:
         if len(eligible) != logits.shape[0]:
             logits = logits[eligible]
         if logits.shape[0] == 0:       # nothing to sample this iteration
-            self._on_sampled(sched, np.zeros(0, np.int32))
+            with span("complete"):
+                self._on_sampled(sched, np.zeros(0, np.int32))
             return
         eligible_ids = [sched.seq_ids[i] for i in eligible]
         # per-request sampling params are an API contract: each column
         # samples with ITS OWN request's params, even in mixed batches
         # (the pre-redesign engine applied seq_ids[0]'s params batch-wide)
         params = [self.scheduler.seqs[sid].params for sid in eligible_ids]
-        out = self._pool_sample(sched.iteration, sched.slot, eligible_ids,
-                                logits, params)
+        with span("sample"):
+            out = self._pool_sample(sched.iteration, sched.slot,
+                                    eligible_ids, logits, params)
         self.sample_time += time.monotonic() - t0
-        self._on_sampled(sched, out)
+        with span("complete"):
+            self._on_sampled(sched, out)
 
     def _pool_sample(self, iteration: int, slot: int, seq_ids: List[int],
                      logits: np.ndarray,
@@ -980,7 +1035,8 @@ class PPEngineBase:
                   and d.sample_indices()]:
             self._await_iteration(d)
             inflight.remove(d)
-        sched = self.scheduler.schedule(it)
+        with span("schedule"):
+            sched = self.scheduler.schedule(it)
         self._reap_preempted()
         if sched is not None:
             while sched is not None and sched.is_prefill:
@@ -992,7 +1048,8 @@ class PPEngineBase:
                 while inflight:
                     self._await_iteration(inflight.pop(0))
                 self._admit_and_prefill(sched)
-                sched = self.scheduler.schedule(it)  # rebuilt after prefill
+                with span("schedule"):               # rebuilt after prefill
+                    sched = self.scheduler.schedule(it)
                 self._reap_preempted()
             if sched is not None:
                 # span policies admit KV rows lazily, on first chunk.  An
@@ -1191,17 +1248,20 @@ class PPEngineBase:
         raise NotImplementedError
 
     def _await_iteration(self, sched: SchedulingOutput):
-        deadline = time.monotonic() + ITER_STALL_S
-        while sched.iteration not in self.iter_done_t:
-            if self.sampling_worker is not None:
-                self.sampling_worker.check()   # surface sampler crashes
-            now = time.monotonic()
-            if any(w.executing for w in self.stages):
-                deadline = now + ITER_STALL_S
-            elif now > deadline:
-                raise TimeoutError(
-                    f"iteration {sched.iteration} never completed")
-            time.sleep(0.0005)
+        t0 = time.monotonic()
+        deadline = t0 + ITER_STALL_S
+        with span("gate"):
+            while sched.iteration not in self.iter_done_t:
+                if self.sampling_worker is not None:
+                    self.sampling_worker.check()   # surface sampler crashes
+                now = time.monotonic()
+                if any(w.executing for w in self.stages):
+                    deadline = now + ITER_STALL_S
+                elif now > deadline:
+                    raise TimeoutError(
+                        f"iteration {sched.iteration} never completed")
+                time.sleep(0.0005)
+        self.gate_wait_s += time.monotonic() - t0
 
     def compile_stats(self) -> Dict[str, int]:
         """Total jit executables across the stage step functions — the
@@ -1243,15 +1303,10 @@ class PPEngineBase:
         toks = self._tokens_finished + sum(
             len(r.seq.output_ids) for r in self.requests.values()
             if r.seq.status == SeqStatus.FINISHED)   # finished, not yet drained
-        per_stage = []
-        for w in self.stages:
-            busy = sum(e - s for s, e in w.metrics.busy)
-            per_stage.append({
-                "busy_s": busy,
-                "prep_s": w.executor.prep_time,
-                "exec_s": w.executor.exec_time,
-                "bubble_frac": max(0.0, 1.0 - busy / wall),
-            })
+        counters = [w.counters() for w in self.stages]
+        per_stage = [{"prep_s": w.executor.prep_time,
+                      "exec_s": w.executor.exec_time, **c}
+                     for w, c in zip(self.stages, counters)]
         stats = list(self._request_stats)
         # latency percentiles are ONLINE-tier only (docs/hybrid.md):
         # offline rows would drag the SLO metrics the admission layer and
@@ -1300,6 +1355,7 @@ class PPEngineBase:
             # per-request latency records over the retained window
             "requests": {r.request_id: r.as_dict() for r in stats},
             "sample_s": self.sample_time,
+            "gate_wait_s": self.gate_wait_s,
             "stages": per_stage,
             "incremental_hits": sum(w.meta_cache.incremental_hits for w in self.stages),
             "meta_rebuilds": sum(w.meta_cache.rebuilds for w in self.stages),
@@ -1322,6 +1378,10 @@ class PPEngineBase:
             out["kv_table_widths"] = self.kv_manager.table_widths
             for k, v in self.kv_manager.prefix_stats().items():
                 out[f"kv_{k}"] = v
+        # the stages' counters again as flat scalars, which /metrics exports
+        for i, c in enumerate(counters):
+            for k, v in c.items():
+                out[f"stage{i}_{k}"] = v
         out.update(self.compile_stats())
         for k, v in self.scheduler.policy.metrics().items():
             out[f"policy_{k}"] = v

@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.scheduler import SchedulingOutput
+from repro.core.tracing import span
 
 
 @dataclasses.dataclass
@@ -211,8 +212,10 @@ class TokenSafeExecutor:
         self._results: Dict[int, Any] = {}
         self.prep_time = 0.0
         self.exec_time = 0.0
-        self.stall_time = 0.0
+        self.stall_time = 0.0           # device thread with nothing prepared
         self._threads: List[threading.Thread] = []
+        self._prepare_span = f"{name}/prepare"
+        self._wait_span = f"{name}/wait_input"
 
     # -- communicator API ----------------------------------------------------
     def submit(self, sched: SchedulingOutput):
@@ -249,7 +252,8 @@ class TokenSafeExecutor:
                   else sched.block_tables.shape[1])
             bufs = self.staging.buffers(version, len(sched.seq_ids), width,
                                         nb)
-            self.prepare_fn(sched, bufs)
+            with span(self._prepare_span):
+                self.prepare_fn(sched, bufs)
             self.prep_time += time.monotonic() - t0
             with self._cv:
                 self.ci += 1
@@ -261,7 +265,7 @@ class TokenSafeExecutor:
     def _device_loop(self):
         while True:
             t_wait = time.monotonic()
-            with self._cv:
+            with span(self._wait_span), self._cv:
                 while not self._stop and not self._input_q:
                     self._cv.wait(0.05)
                 if self._stop:
@@ -305,13 +309,15 @@ class SynchronousExecutor:
         self.prep_time = 0.0
         self.exec_time = 0.0
         self.stall_time = 0.0
+        self._prepare_span = f"{name}/prepare"
 
     def run(self, sched: SchedulingOutput) -> Any:
         width = sched.packed_width
         nb = 0 if sched.block_tables is None else sched.block_tables.shape[1]
         bufs = self.staging.buffers(0, len(sched.seq_ids), width, nb)
         t0 = time.monotonic()
-        self.prepare_fn(sched, bufs)
+        with span(self._prepare_span):
+            self.prepare_fn(sched, bufs)
         t1 = time.monotonic()
         out = self.execute_fn(
             ModelInputDescriptor(sched.iteration, 0, len(sched.seq_ids),

@@ -649,6 +649,7 @@ def paged_span_attention(q: jax.Array, k_cache: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h, hd), q.dtype),
         interpret=interpret,
+        name="paged_span_attention",
     )(seq_idx, positions, tbl, q, k_cache, v_cache)
     return out.reshape(t, h * hd)
 
@@ -696,6 +697,7 @@ def paged_span_attention_quant(q: jax.Array, k8: jax.Array, ks: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h, hd), q.dtype),
         interpret=interpret,
+        name="paged_span_attention_quant",
     )(seq_idx, positions, tbl, q, k8, ks, v8, vs)
     return out.reshape(t, h * hd)
 
@@ -753,6 +755,7 @@ def paged_span_attention_rolling(q: jax.Array, k_cache: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h, hd), q.dtype),
         interpret=interpret,
+        name="paged_span_attention_rolling",
     )(seq_idx, positions, offsets, n_valid, tbl, q, k_cache, v_cache,
       k_span, v_span, positions, seq_idx)
     return out.reshape(t, h * hd)
@@ -817,6 +820,7 @@ def paged_span_attention_rolling_quant(q: jax.Array, k8: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h, hd), q.dtype),
         interpret=interpret,
+        name="paged_span_attention_rolling_quant",
     )(seq_idx, positions, offsets, n_valid, tbl, q, k8, ks, v8, vs,
       k_span, v_span, positions, seq_idx)
     return out.reshape(t, h * hd)

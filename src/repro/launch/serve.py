@@ -412,8 +412,11 @@ def _print_metrics(m: dict):
                       if k not in ("stages", "requests")},
                      indent=1, default=float))
     for i, st in enumerate(m["stages"]):
-        print(f"  stage{i}: busy={st['busy_s']:.2f}s "
-              f"prep={st['prep_s']:.2f}s bubble={st['bubble_frac']:.2f}")
+        print(f"  stage{i}: prep={st['prep_s']:.2f}s exec={st['exec_s']:.2f}s"
+              f" input_wait={st['input_wait_s']:.2f}s"
+              f" recv_wait={st['recv_wait_s']:.2f}s d2h={st['d2h_s']:.2f}s"
+              f" steps decode={st['decode_steps']}"
+              f" chunk={st['chunk_steps']}")
 
 
 def main():

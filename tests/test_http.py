@@ -401,3 +401,30 @@ def test_abort_inside_fork_spawn_window_leaks_nothing(model_and_params):
     m = eng.metrics()
     assert not eng.has_work
     assert m["kv_blocks_free"] == m["kv_blocks_total"]
+
+
+def test_http_metrics_show_stage_waits_of_a_real_engine(model_and_params):
+    """An operator's scrape carries each stage's wait and copy counters
+    and the driver's gate wait (docs/http.md)."""
+    cfg, model, params = model_and_params
+    srv = _http_over(cfg, model, params)
+    try:
+        conn, resp = _request(srv.address, {
+            "prompt": [5, 9, 13], "max_tokens": 6, "temperature": 0.0},
+            timeout=120.0)
+        assert resp.status == 200
+        resp.read()
+        conn.close()
+        conn, resp = _request(srv.address, method="GET", path="/metrics")
+        text = resp.read().decode()
+        conn.close()
+        gauges = dict(ln.rsplit(" ", 1) for ln in text.splitlines())
+        for i in (0, 1):
+            for key in ("input_wait_s", "recv_wait_s", "d2h_s",
+                        "decode_steps", "decode_tokens", "chunk_steps"):
+                assert f'repro_stage{i}_{key}{{replica="r0"}}' in gauges
+        assert float(gauges['repro_gate_wait_s{replica="r0"}']) > 0
+        assert float(gauges['repro_stage1_recv_wait_s{replica="r0"}']) > 0
+        assert float(gauges['repro_stage1_decode_sampled{replica="r0"}']) > 0
+    finally:
+        srv.close()

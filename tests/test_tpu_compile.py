@@ -156,6 +156,18 @@ def test_stage_chunk_step_compiles(one_chip, pallas, window):
         sp, cache, i32((T,)), i32((T,)), i32((T,)), i32((B,)), i32((B,)),
         i32(()), i32((B, NB))).compile().as_text()
     assert "tpu_custom_call" in text
+    # a device trace names the program after the stage, and each op by
+    # its HLO text: the benchmark finds the kernel by the label it makes
+    # of that text, which the kernel's name must leave alone
+    from bench.trace import label
+
+    assert text.startswith("HloModule jit_chunk_fn_stage0")
+    kernels = [label(ln.strip()) for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels
+    for k in kernels:
+        assert k.startswith("paged_span_attention")
+        assert "custom-call:tpu_custom_call" in k
 
 
 def test_stage_decode_step_compiles(one_chip):
