@@ -428,14 +428,17 @@ def cross_attention(
 # Paged KV cache (block tables) — oracles for the paged Pallas kernels
 # ---------------------------------------------------------------------------
 
-def gather_paged_cache(cache: jax.Array, block_tables: jax.Array) -> jax.Array:
+def gather_paged_cache(cache: jax.Array, block_tables: jax.Array,
+                       layer: Optional[jax.Array] = None) -> jax.Array:
     """[n_blocks, bs, ...] physical cache + [B, nb] block table ->
     [B, nb * bs, ...] per-sequence contiguous view: logical slot p of row
-    i is ``cache[block_tables[i, p // bs], p %% bs]``.  Padded table
+    i is ``cache[block_tables[i, p // bs], p %% bs]``.  With ``layer``,
+    ``cache`` is a stacked [n, n_blocks, bs, ...] pool and the layer index
+    rides in the same gather, so no layer slice is made.  Padded table
     entries gather arbitrary blocks — always position-masked downstream."""
     b, nb = block_tables.shape
-    g = cache[block_tables]                       # [B, nb, bs, ...]
-    return g.reshape(b, nb * cache.shape[1], *cache.shape[2:])
+    g = cache[block_tables] if layer is None else cache[layer, block_tables]
+    return g.reshape(b, nb * g.shape[2], *g.shape[3:])   # g [B, nb, bs, ...]
 
 
 def paged_span_attention(

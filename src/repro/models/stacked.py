@@ -48,6 +48,11 @@ class Ctx:
     fuse_shared_expert: bool = False
     seq_shard: bool = False
     kv_quant: bool = False
+    # set by run_stack alone (decode over a block-paged pool, for a stack
+    # that addresses it): cache leaves are then the stack's whole carried
+    # pool [n, n_blocks, bs, ...] and ``layer`` is the group's index into
+    # its leading axis
+    layer: Optional[jax.Array] = None
 
 
 @dataclasses.dataclass
@@ -65,6 +70,9 @@ class Stack:
     apply: Callable
     cache_spec: Optional[Callable] = None  # (B, cache_len) -> per-group SDS tree
     cache_axes: Optional[Callable] = None  # () -> matching logical-axes tree
+    # apply's blocks read and write a block-paged pool at ``ctx.layer``,
+    # so decode can hand them the whole stacked pool, not a group slice
+    addresses_stacked_pool: bool = False
 
 
 def stack_specs(stack: Stack, axis_name: str = "layers") -> PyTree:
@@ -86,24 +94,16 @@ def run_stack(
     remat: bool = True,
 ) -> tuple:
     """Scan a stack; returns (x, stacked caches or None)."""
+    if ctx.mode in ("decode", "chunk"):
+        return _run_stack_carried(stack, params_stacked, x, ctx, cache_stacked)
     if stack.n == 1:
         gp = jax.tree.map(lambda p: p[0], params_stacked)
-        cg = jax.tree.map(lambda c: c[0], cache_stacked) if cache_stacked is not None else None
-        fn = lambda g, xc, c: stack.apply(g, xc, ctx, c)
+        fn = lambda g, xc: stack.apply(g, xc, ctx, None)
         if remat and ctx.mode == "train":
             fn = jax.checkpoint(fn)
-        x, new_c = fn(gp, x, cg)
+        x, new_c = fn(gp, x)
         pack = (lambda t: jax.tree.map(lambda l: l[None], t)) if new_c is not None else (lambda t: None)
         return x, pack(new_c)
-
-    if ctx.mode in ("decode", "chunk"):
-        def body(xc, inp):
-            gp, cg = inp
-            xo, ncg = stack.apply(gp, xc, ctx, cg)
-            return xo, ncg
-
-        x, new_cache = jax.lax.scan(body, x, (params_stacked, cache_stacked))
-        return x, new_cache
 
     def body(xc, gp):
         xo, cg = stack.apply(gp, xc, ctx, None)
@@ -113,6 +113,59 @@ def run_stack(
         body = jax.checkpoint(body)
     x, caches = jax.lax.scan(body, x, params_stacked)
     return x, caches
+
+
+# The chip's default layout for a pool leaf whose minor axis does not
+# fill whole 128-lane tiles (a 64-wide head, the int8 pool's [.., kv]
+# scales) puts another axis minor.  A scatter into the whole pool then
+# makes the compiler convert all of it on the way into and out of the
+# step, where a layer's slice converts only that layer.
+_LANES = 128
+
+
+def carries_whole_pool(stack: Stack, ctx: Ctx, cache: Optional[PyTree]) -> bool:
+    """Whether the stack gets the whole carried pool and ``ctx.layer``:
+    a decode step over a paged pool that the stack's blocks address and
+    whose leaves' rows fill whole lane tiles.  A chunk step's span kernel
+    reads one layer's [n_blocks, bs, ...] anyway; slicing it before the
+    scatter lets the compiler keep that slice in on-chip memory, which a
+    slice taken from the carried pool after the scatter is not given on
+    the last stage."""
+    return (ctx.mode == "decode" and stack.addresses_stacked_pool
+            and ctx.block_tables is not None
+            and all(c.shape[-1] % _LANES == 0 for c in jax.tree.leaves(cache)))
+
+
+def _run_stack_carried(stack: Stack, params_stacked: PyTree, x: jax.Array,
+                       ctx: Ctx, cache_stacked: Optional[PyTree]) -> tuple:
+    """Decode/chunk: scan the parameters with ``(x, cache, layer)`` as the
+    carry, so the stacked cache is updated in place (docs/memory.md).
+
+    Where :func:`carries_whole_pool` holds, the stack gets the whole
+    carried pool plus ``ctx.layer`` and scatters its dirty slots straight
+    into it.  Otherwise it gets its group's slice of the carry and writes
+    it back at the same index: either way there is no per-layer ``ys``
+    buffer to copy out after the loop."""
+    whole = carries_whole_pool(stack, ctx, cache_stacked)
+
+    def body(carry, gp):
+        xc, cache, i = carry
+        if whole:
+            xo, cache = stack.apply(gp, xc, dataclasses.replace(ctx, layer=i),
+                                    cache)
+        else:
+            cg = jax.tree.map(
+                lambda c: jax.lax.dynamic_index_in_dim(c, i, keepdims=False),
+                cache)
+            xo, ncg = stack.apply(gp, xc, ctx, cg)
+            cache = jax.tree.map(
+                lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+                cache, ncg)
+        return (xo, cache, i + 1), None
+
+    (x, cache, _), _ = jax.lax.scan(
+        body, (x, cache_stacked, jnp.zeros((), jnp.int32)), params_stacked)
+    return x, cache
 
 
 def abstract_cache_tree(stack: Stack, batch: int, cache_len: int) -> Optional[PyTree]:

@@ -108,40 +108,30 @@ def self_attn_block(p, x, ctx: Ctx, cache, cfg: ArchConfig, *, causal=True,
         b = x.shape[0]
         slot = ctx.positions % w if w else ctx.positions
         if ctx.block_tables is not None:
-            # paged KV: cache leaves are block-major [n_blocks, bs, ...].
-            # Scatter ONLY the new token's physical (block, offset) slot —
-            # the dirty-slot write-back — then attend the per-row gathered
-            # view (decode's full softmax reads every slot anyway; XLA
-            # fuses the gather, and masked trash contributes exactly 0.0).
+            # paged KV: cache leaves are block-major [n_blocks, bs, ...]
+            # (or, under ``ctx.layer``, the stack's carried pool with the
+            # layer axis in front).  Scatter ONLY the new token's physical
+            # (block, offset) slot — the dirty-slot write-back, in place —
+            # then attend the per-row gathered view (decode's full softmax
+            # reads every slot anyway; XLA fuses the gather, and masked
+            # trash contributes exactly 0.0).
             tables = ctx.block_tables                    # [B, nb]
-            bs = cache["k"].shape[1]
+            bs = cache["k"].shape[-3]
             blk = jnp.minimum(slot // bs, tables.shape[1] - 1)
-            phys = tables[jnp.arange(b), blk]
-            off = slot % bs
-            if "ks" in cache:
-                k8, ks1 = attn.quantize_kv(k)
-                v8, vs1 = attn.quantize_kv(v)
-                new_cache = {
-                    "k": cache["k"].at[phys, off].set(k8),
-                    "v": cache["v"].at[phys, off].set(v8),
-                    "ks": cache["ks"].at[phys, off].set(ks1),
-                    "vs": cache["vs"].at[phys, off].set(vs1),
-                }
+            at = (tables[jnp.arange(b), blk], slot % bs)
+            if ctx.layer is not None:
+                at = (ctx.layer,) + at
+            new_cache = _paged_write(cache, at, k, v)
+            view = {n: attn.gather_paged_cache(c, tables, ctx.layer)
+                    for n, c in new_cache.items()}
+            if "ks" in view:
                 o = attn.decode_attention_quant(
-                    q,
-                    attn.gather_paged_cache(new_cache["k"], tables),
-                    attn.gather_paged_cache(new_cache["ks"], tables),
-                    attn.gather_paged_cache(new_cache["v"], tables),
-                    attn.gather_paged_cache(new_cache["vs"], tables),
+                    q, view["k"], view["ks"], view["v"], view["vs"],
                     ctx.positions, rolling_window=w)
-                return x + o @ p["wo"], new_cache
-            kc = cache["k"].at[phys, off].set(k)
-            vc = cache["v"].at[phys, off].set(v)
-            o = attn.decode_attention(
-                q, attn.gather_paged_cache(kc, tables),
-                attn.gather_paged_cache(vc, tables),
-                ctx.positions, rolling_window=w)
-            return x + o @ p["wo"], {"k": kc, "v": vc}
+            else:
+                o = attn.decode_attention(q, view["k"], view["v"],
+                                          ctx.positions, rolling_window=w)
+            return x + o @ p["wo"], new_cache
         rows = jnp.arange(b)
         if "ks" in cache:  # §Perf C1: int8 cache, s8xs8 attention dots
             k8, ks1 = attn.quantize_kv(k)
@@ -197,28 +187,17 @@ def self_attn_block(p, x, ctx: Ctx, cache, cfg: ArchConfig, *, causal=True,
                 bs = cache["k"].shape[1]
                 slot = ctx.positions % w
                 blk = jnp.minimum(slot // bs, tables.shape[1] - 1)
-                phys = tables[si, blk]
-                off = slot % bs
-                if "ks" in (cache or {}):
+                at = (tables[si, blk], slot % bs)
+                if "ks" in cache:
                     o = attn.paged_span_attention_rolling_quant_exec(
                         q, cache["k"], cache["ks"], cache["v"], cache["vs"],
                         k, v, tables, ctx.positions, si, offs, n_valid,
                         window=w)
-                    k8, ks1 = attn.quantize_kv(k)
-                    v8, vs1 = attn.quantize_kv(v)
-                    new_cache = {
-                        "k": cache["k"].at[phys, off].set(k8),
-                        "v": cache["v"].at[phys, off].set(v8),
-                        "ks": cache["ks"].at[phys, off].set(ks1),
-                        "vs": cache["vs"].at[phys, off].set(vs1),
-                    }
-                    return x + o @ p["wo"], new_cache
-                o = attn.paged_span_attention_rolling_exec(
-                    q, cache["k"], cache["v"], k, v, tables, ctx.positions,
-                    si, offs, n_valid, window=w)
-                kc = cache["k"].at[phys, off].set(k)
-                vc = cache["v"].at[phys, off].set(v)
-                return x + o @ p["wo"], {"k": kc, "v": vc}
+                else:
+                    o = attn.paged_span_attention_rolling_exec(
+                        q, cache["k"], cache["v"], k, v, tables,
+                        ctx.positions, si, offs, n_valid, window=w)
+                return x + o @ p["wo"], _paged_write(cache, at, k, v)
             if "ks" in (cache or {}):
                 o = attn.packed_span_attention_rolling_quant(
                     q, cache["k"], cache["ks"], cache["v"], cache["vs"],
@@ -251,26 +230,16 @@ def self_attn_block(p, x, ctx: Ctx, cache, cfg: ArchConfig, *, causal=True,
             tables = ctx.block_tables
             bs = cache["k"].shape[1]
             blk = jnp.minimum(ctx.positions // bs, tables.shape[1] - 1)
-            phys = tables[si, blk]
-            off = ctx.positions % bs
-            if "ks" in (cache or {}):
-                k8, ks1 = attn.quantize_kv(k)
-                v8, vs1 = attn.quantize_kv(v)
-                new_cache = {
-                    "k": cache["k"].at[phys, off].set(k8),
-                    "v": cache["v"].at[phys, off].set(v8),
-                    "ks": cache["ks"].at[phys, off].set(ks1),
-                    "vs": cache["vs"].at[phys, off].set(vs1),
-                }
+            kv = _paged_write(cache, (tables[si, blk], ctx.positions % bs),
+                              k, v)
+            if "ks" in kv:
                 o = attn.paged_span_attention_quant_exec(
-                    q, new_cache["k"], new_cache["ks"], new_cache["v"],
-                    new_cache["vs"], tables, ctx.positions, si)
-                return x + o @ p["wo"], new_cache
-            kc = cache["k"].at[phys, off].set(k)
-            vc = cache["v"].at[phys, off].set(v)
-            o = attn.paged_span_attention_exec(q, kc, vc, tables,
-                                               ctx.positions, si)
-            return x + o @ p["wo"], {"k": kc, "v": vc}
+                    q, kv["k"], kv["ks"], kv["v"], kv["vs"], tables,
+                    ctx.positions, si)
+            else:
+                o = attn.paged_span_attention_exec(q, kv["k"], kv["v"], tables,
+                                                   ctx.positions, si)
+            return x + o @ p["wo"], kv
         if "ks" in (cache or {}):
             k8, ks1 = attn.quantize_kv(k)
             v8, vs1 = attn.quantize_kv(v)
@@ -335,6 +304,16 @@ def self_attn_block(p, x, ctx: Ctx, cache, cfg: ArchConfig, *, causal=True,
                 "v": shard.constrain(vc, ca),
             }
     return x, new_cache
+
+
+def _paged_write(cache, at, k: jax.Array, v: jax.Array) -> Dict:
+    """Scatter the new K/V into the paged slots ``at`` (int8 with its
+    scales where the pool is quantized)."""
+    if "ks" not in cache:
+        return {"k": cache["k"].at[at].set(k), "v": cache["v"].at[at].set(v)}
+    (k8, ks), (v8, vs) = attn.quantize_kv(k), attn.quantize_kv(v)
+    return {"k": cache["k"].at[at].set(k8), "v": cache["v"].at[at].set(v8),
+            "ks": cache["ks"].at[at].set(ks), "vs": cache["vs"].at[at].set(vs)}
 
 
 def _cache_axes(cfg: ArchConfig, tp: int) -> Tuple:
@@ -504,7 +483,8 @@ def dense_layer_stack(cfg: ArchConfig, tp: int, n: int, *, moe_every: int = 0,
     def cache_axes():
         return {f"l{i}": caxes() for i in range(per)}
 
-    return Stack("blocks", n, group_specs, apply, cache_spec, cache_axes)
+    return Stack("blocks", n, group_specs, apply, cache_spec, cache_axes,
+                 addresses_stacked_pool=True)
 
 
 def vlm_stack(cfg: ArchConfig, tp: int) -> Stack:

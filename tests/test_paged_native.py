@@ -295,3 +295,98 @@ def test_untouched_blocks_survive_garbage_poking(model_and_params):
         return sorted(done.items())
 
     assert run("paged", poison=True) == run("contiguous", poison=False)
+
+
+# ---------------------------------------------------------------------------
+# Stage step: the carried layer scan against the per-layer xs/ys scan
+# ---------------------------------------------------------------------------
+
+def _xs_ys_scan(stack, params, x, ctx, cache):
+    """The per-layer reference: each group's cache slice goes in as a
+    scan input and comes out as a fresh stacked output."""
+    def body(xc, inp):
+        gp, cg = inp
+        return stack.apply(gp, xc, ctx, cg)
+
+    return jax.lax.scan(body, x, (params, cache))
+
+
+def _paged_pool(abstract, n_phys, bs, rng):
+    """Random block-major pool: attention leaves [n, B, S, ...] become
+    [n, n_phys, bs, ...]; recurrent state leaves keep their shape."""
+    def leaf(path, sd):
+        shape = sd.shape
+        if getattr(path[-1], "key", None) in ("k", "v", "ks", "vs"):
+            shape = (shape[0], n_phys, bs) + shape[3:]
+        if sd.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        return _rand(rng, shape, sd.dtype)
+
+    return jax.tree_util.tree_map_with_path(leaf, abstract)
+
+
+# head_dim 128 fills a lane tile, so decode carries those pools whole;
+# chunk steps, the smoke archs' 16-wide heads and the int8 pool's scales
+# take the slice
+@pytest.mark.parametrize("arch,head_dim,kv_quant,mode,whole", [
+    ("stablelm-1.6b-smoke", 128, False, "decode", True),
+    ("stablelm-1.6b-smoke", 128, False, "chunk", False),
+    ("stablelm-1.6b-smoke", 128, True, "decode", False),
+    ("stablelm-1.6b-smoke", 128, True, "chunk", False),
+    ("mixtral-8x7b-smoke", 128, False, "decode", True),   # moe, window
+    ("mixtral-8x7b-smoke", 128, False, "chunk", False),
+    ("stablelm-1.6b-smoke", 0, False, "decode", False),   # 16-wide head
+    ("recurrentgemma-9b-smoke", 0, False, "decode", False),
+], ids=["bf16-decode", "bf16-chunk", "int8-decode", "int8-chunk",
+        "moe-window-decode", "moe-window-chunk", "narrow-head-decode",
+        "hybrid-decode"])
+def test_carried_scan_equals_per_layer_scan(arch, head_dim, kv_quant, mode,
+                                            whole):
+    """Paged decode and chunk through run_stack's carried scan (in decode
+    the whole pool where the stack addresses it and its rows fill lane
+    tiles, else a slice and write-back, as for the hybrid stack's RG-LRU
+    state) give the same logits, bit for bit, and the same returned pool
+    as the per-layer xs/ys scan."""
+    from repro.models.stacked import carries_whole_pool, run_stack
+
+    cfg = get_config(arch)
+    if head_dim:
+        cfg = dataclasses.replace(cfg, head_dim=head_dim)
+    model = build_model(cfg, ShardCtx.single(), ModelOptions(kv_quant=kv_quant))
+    params = model.init(jax.random.key(5))
+    stack = model.stacks["blocks"]
+    b, s, bs = 3, 32, 8
+    rng = np.random.default_rng(41)
+    tables, n_phys, nb = _paged_layout(rng, b, s, bs)
+    abstract = model.abstract_cache(b, s)["blocks"]
+    cache = _paged_pool(abstract, n_phys + 1, bs, rng)   # + trash block
+    tables = jnp.asarray(tables)
+    if mode == "decode":
+        pos = jnp.asarray(rng.integers(0, s, b), jnp.int32)
+        ctx = model.make_ctx("decode", pos, block_tables=tables)
+        x = _rand(rng, (b, cfg.d_model))
+    else:
+        lens, starts = np.array([3, 1, 4]), np.array([5, 10, 0])
+        seq = np.repeat(np.arange(b), lens).astype(np.int32)
+        pos = np.concatenate([st + np.arange(n) for st, n in zip(starts, lens)])
+        ctx = model.make_ctx("chunk", jnp.asarray(pos, jnp.int32),
+                             seq_idx=jnp.asarray(seq),
+                             span_starts=jnp.asarray(starts, jnp.int32),
+                             n_valid=jnp.asarray(len(seq), jnp.int32),
+                             block_tables=tables)
+        x = _rand(rng, (len(seq), cfg.d_model))
+    assert carries_whole_pool(stack, ctx, cache) == whole
+
+    def step(scan):
+        def f(prm, x, cache):
+            xo, new = scan(stack, prm["stacks"]["blocks"], x, ctx, cache)
+            return model.lm_head(prm, xo), new
+        return jax.jit(f)(params, x, cache)
+
+    got = step(lambda *a: run_stack(*a, remat=False))
+    ref = step(_xs_ys_scan)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(_bits(g), _bits(r))
+    # the step wrote something: the pool is not what went in
+    assert any(not np.array_equal(_bits(n), _bits(o)) for n, o in
+               zip(jax.tree.leaves(got[1]), jax.tree.leaves(cache)))

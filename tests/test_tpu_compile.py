@@ -1,6 +1,7 @@
 """Compile the serving hot path for a TPU v5e that is described, not
 attached: the four paged span-attention twins through the engine's
-dispatchers, and one full-width paged stage chunk step and decode step.
+dispatchers, full-width paged stage chunk and decode steps, and the
+benchmark's glm4-9b stage steps, whose KV pool must be updated in place.
 
 Nothing runs here.  The TPU compiler refuses what the chip would refuse
 (unaligned tiles, too much VMEM, scalar-prefetch shapes), so these tests
@@ -15,6 +16,7 @@ imports this file.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core.engine import _make_stage
-from repro.models import attention, build_model
+from repro.models import ModelOptions, attention, build_model
 
 BS = 16           # KV block size the engine serves with
 T, B, NB = 256, 8, 16
@@ -116,10 +118,10 @@ def test_paged_rolling_twin_compiles(one_chip, pallas, quant):
     assert "tpu_custom_call" in text
 
 
-def _stage(cfg, idx, p, sharding):
+def _stage(cfg, idx, p, sharding, n_blocks=N_BLOCKS, options=ModelOptions()):
     """Stage ``idx`` of a ``p``-stage paged split of ``cfg``, with its
     parameters and KV pool as shapes on the described chip."""
-    model = build_model(cfg)
+    model = build_model(cfg, options=options)
     n = model.stacks["blocks"].n
     lo, hi = round(idx * n / p), round((idx + 1) * n / p)
 
@@ -138,7 +140,7 @@ def _stage(cfg, idx, p, sharding):
     stage = _make_stage(model, idx, p, (lo, hi), sp, paged=True)
     template = jax.eval_shape(lambda: stage.init_cache(1, 1))
     cache = jax.tree.map(
-        lambda c: _sds((c.shape[0], N_BLOCKS, BS) + c.shape[3:], c.dtype,
+        lambda c: _sds((c.shape[0], n_blocks, BS) + c.shape[3:], c.dtype,
                        sharding), template)
     return stage, sp, cache
 
@@ -179,3 +181,98 @@ def test_stage_decode_step_compiles(one_chip):
     stage.decode_fn.lower(
         sp, cache, _sds((B, cfg.d_model), jnp.bfloat16, one_chip),
         i32((B,)), i32((B, NB))).compile()
+
+
+# the benchmark's cell: glm4-9b at published widths, 10 layers as a pp=2
+# pipeline of 5 per stage, B = 16 rows with 160-block tables (2560 slots)
+# over a 6144-block pool plus the trash block
+GLM_BLOCKS, GLM_B, GLM_NB = 6144 + 1, 16, 160
+_POOL_OP = re.compile(r"= (?:bf16|s8)\[([\d,]*)\]\S* "
+                      r"(copy|copy-start|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _glm_stage(idx, sharding, options=ModelOptions()):
+    cfg = dataclasses.replace(get_config("glm4-9b"), num_layers=10)
+    stage, sp, cache = _stage(cfg, idx, 2, sharding, GLM_BLOCKS, options)
+    pool = jax.tree.leaves(cache)[0].shape        # [5, blocks, bs, kv, hd]
+    return cfg, stage, sp, cache, pool
+
+
+def _pool_ops(text, pool):
+    """(op, shape) of every copy, dynamic-slice or dynamic-update-slice in
+    the optimised HLO whose bf16 or int8 result is the whole stacked pool
+    or one layer of it (leading unit dims dropped)."""
+    hits = []
+    for m in _POOL_OP.finditer(text):
+        dims = tuple(int(d) for d in m.group(1).split(",") if d)
+        while dims[:1] == (1,) and len(dims) > len(pool) - 1:
+            dims = dims[1:]
+        if dims in (tuple(pool), tuple(pool[1:])):
+            hits.append((m.group(2), dims))
+    return hits
+
+
+@pytest.mark.parametrize("idx", [0, 1], ids=["first", "last"])
+def test_glm_decode_step_updates_pool_in_place(one_chip, idx):
+    """The decode step scatters each layer's new K/V into the carried
+    pool and gathers the layer's view in the same gather: no copy, slice
+    or write-back of the pool or of a layer of it, and less scratch than
+    one layer's K."""
+    cfg, stage, sp, cache, pool = _glm_stage(idx, one_chip)
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    x = (i32((GLM_B,)) if idx == 0
+         else _sds((GLM_B, cfg.d_model), jnp.bfloat16, one_chip))
+    compiled = stage.decode_fn.lower(
+        sp, cache, x, i32((GLM_B,)), i32((GLM_B, GLM_NB))).compile()
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_decode_fn_stage{idx}")
+    assert _pool_ops(text, pool) == []
+    layer_k = 2 * functools.reduce(lambda a, b: a * b, pool[1:])   # bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k
+
+
+@pytest.mark.parametrize("idx", [0, 1], ids=["first", "last"])
+def test_glm_chunk_step_copies_no_pool(one_chip, pallas, idx):
+    """The chunk step takes each layer's slice of the carried pool for
+    the span kernel and writes it back in place: nothing copies the
+    whole pool, and the step needs less scratch than one layer's K."""
+    cfg, stage, sp, cache, pool = _glm_stage(idx, one_chip)
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    x = i32((T,)) if idx == 0 else _sds((T, cfg.d_model), jnp.bfloat16,
+                                         one_chip)
+    compiled = stage.chunk_fn.lower(
+        sp, cache, x, i32((T,)), i32((T,)), i32((GLM_B,)), i32((GLM_B,)),
+        i32(()), i32((GLM_B, GLM_NB))).compile()
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_chunk_fn_stage{idx}")
+    assert "tpu_custom_call" in text
+    copied = [h for h in _pool_ops(text, pool)
+              if h[0].startswith("copy") and h[1] == tuple(pool)]
+    assert copied == []
+    layer_k = 2 * functools.reduce(lambda a, b: a * b, pool[1:])   # bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k
+
+
+@pytest.mark.parametrize("arch", ["stablelm-bf16", "glm4-int8"])
+def test_narrow_pool_decode_step_copies_no_pool(one_chip, arch):
+    """A pool whose rows do not fill lane tiles is laid out on the chip
+    with another axis minor, so scattering into the whole pool would
+    convert all of it on the way in and out.  Such a pool takes a
+    layer's slice and writes it back: no leaf of the pool is copied
+    whole."""
+    if arch == "glm4-int8":   # its scales are [.., kv = 2]
+        cfg, stage, sp, cache, _ = _glm_stage(1, one_chip,
+                                              ModelOptions(kv_quant=True))
+        b, nb = GLM_B, GLM_NB
+    else:                     # stablelm-1.6b's 64-wide heads
+        cfg = get_config("stablelm-1.6b")
+        stage, sp, cache = _stage(cfg, 1, 2, one_chip)
+        b, nb = B, NB
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    text = stage.decode_fn.lower(
+        sp, cache, _sds((b, cfg.d_model), jnp.bfloat16, one_chip),
+        i32((b,)), i32((b, nb))).compile().as_text()
+    for leaf in jax.tree.leaves(cache):
+        copied = [h for h in _pool_ops(text, leaf.shape)
+                  if h[0].startswith("copy") and h[1] == tuple(leaf.shape)]
+        assert copied == [], leaf.shape
