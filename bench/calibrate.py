@@ -23,20 +23,20 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from bench import reference, registry, run as R, weights  # noqa: E402
+from bench import registry, run as R  # noqa: E402
 
 
-def readings(cfg, cell, seed, reqs, picked) -> dict:
-    """The served tokens' widest gap below the float32 reference, and the
-    float8 reference control's at the same positions."""
-    w = weights.make(cfg, seed)
+def readings(cfg, block, cell, seed, reqs, picked) -> dict:
+    """The served tokens' widest gap below the block's float32
+    reference, and its float8 control's at the same positions."""
+    w = block.make(cfg, seed)
     served, ctrl, n = [], [], 0
     for r in picked:
         prompt = reqs[r["i"]]["prompt"]
-        g = reference.served_gaps(w, cfg, prompt, r["tokens"])
+        g = block.served_gaps(w, cfg, prompt, r["tokens"])
         served.append(float(g.max()))
         n += len(g)
-        ctrl.append(float(reference.control_gaps(
+        ctrl.append(float(block.control_gaps(
             w, cfg, prompt, r["tokens"]).max()))
     del w
     return {"requests": len(picked), "tokens": n,
@@ -45,8 +45,9 @@ def readings(cfg, cell, seed, reqs, picked) -> dict:
             "fp8_control_per_request": ctrl}
 
 
-def one(cfg, cell, mix, seed, seconds, kv_quant: bool) -> dict:
-    server, eng, _ = R.build(cfg, seed, kv_quant=kv_quant, warm=False)
+def one(cfg, block, cell, mix, seed, seconds, kv_quant: bool) -> dict:
+    server, eng, _ = R.build(cfg, block, seed, kv_quant=kv_quant,
+                             warm=False)
     reqs = R.plan_requests(cell, mix, cfg, seconds, seed)
     ctx = R.drive(server, eng, cell, reqs, seconds)
     state = R.state_below_dtype(eng, cfg["dtype"])
@@ -56,7 +57,7 @@ def one(cfg, cell, mix, seed, seconds, kv_quant: bool) -> dict:
     picked = R.sample(ctx["records"], cell["check"], seed)
     out = {"seed": seed, "kv_quant": kv_quant,
            "state_not_" + cfg["dtype"]: state}
-    out.update(readings(cfg, cell, seed, reqs, picked))
+    out.update(readings(cfg, block, cell, seed, reqs, picked))
     return out
 
 
@@ -73,11 +74,12 @@ def main(argv=None):
     R.chip(wl)
     cell = registry.cell(args.workload)
     cfg = registry.config(wl["config"])
+    block = registry.block(cfg["block"])
     mix = registry.traffic(wl["traffic"])
     for kv_quant, seeds in ((False, args.seeds), (True, args.control_seeds)):
         for seed in seeds:
-            print(json.dumps(one(cfg, cell, mix, seed, args.seconds,
-                                 kv_quant)), flush=True)
+            print(json.dumps(one(cfg, block, cell, mix, seed,
+                                 args.seconds, kv_quant)), flush=True)
     return 0
 
 

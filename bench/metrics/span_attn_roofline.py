@@ -1,6 +1,7 @@
 """The paged span-attention kernel's roofline share: the least time of
-the spans' attention (FLOPs and bytes from shapes, whatever implements
-them) over the kernel's device time, in percent."""
+the spans' attention (the block's ``span_attn_work``: FLOPs and bytes
+from shapes, whatever implements them) over the kernel's device time,
+in percent."""
 from bench import flops, trace
 from bench.stats import steps_in_window
 
@@ -12,12 +13,10 @@ KERNEL = "custom-call:tpu_custom_call"
 def read(ctx):
     if "events" not in ctx:
         return None
-    m, p = ctx["dims"], ctx["peaks"]
+    m, block, p = ctx["dims"], ctx["block"], ctx["peaks"]
     least = 0.0
     for s in steps_in_window(ctx, "chunk"):
-        f = s["layers"] * flops.attn_flops(m, s["ctx_sum"])
-        b = flops.span_attn_bytes(m, s["layers"], s["tokens"],
-                                  s["rows_ctx_sum"])
+        f, b = block.span_attn_work(m, s)
         least += flops.roofline_s(f, b, p["bf16_flops"],
                                   p["hbm_bytes_per_s"])[0]
     dev = trace.matching_seconds(ctx["events"], ctx["trace_lo"],

@@ -1,9 +1,16 @@
-"""The system under test, as the benchmark drives it: the program's
-model built at a configuration file's widths, the benchmark's weights
-put into the program's parameter tree, and the HTTP server over one
-SiPipe engine replica.  Besides these, the harness takes from the
-program only its compile-cache switch and its chunk bucket rule
-(``bench/run.py``); the reference takes nothing."""
+"""The system under test, as the benchmark drives it: the HTTP server
+over one SiPipe engine replica (:func:`build_server`), and the dense
+block's mapping into the program (:func:`build_model`,
+:func:`program_params`, used through ``bench/blocks/dense.py``).
+
+Which model the engine serves, and how the weights enter its parameter
+tree, is the configuration's block's (``bench/blocks/<name>.py``); a
+block checks its tree with :func:`fit`.  Besides the server and the
+model, ``bench/run.py`` takes from the program its compile-cache
+switch, its chunk bucket rule, the stage step functions (warm-up), the
+stage executors' step hooks (traced runs), the engine's counters and
+request records, and, in the trace, its spans and program and kernel
+names.  The reference takes nothing."""
 from __future__ import annotations
 
 import dataclasses
@@ -30,17 +37,14 @@ ARCH_KEYS = {
 
 def arch_config(cfg: dict):
     """The program's ArchConfig for ``cfg``: its named architecture with
-    the file's widths.  A width the program would change is an error."""
+    the file's dense widths (``bench/blocks/dense.py`` refuses any
+    other family)."""
     from repro.configs import get_config
 
     base = get_config(cfg["program"]["arch"])
-    arch = dataclasses.replace(
+    return dataclasses.replace(
         base, **{k: type(getattr(base, k))(cfg[v])
                  for k, v in ARCH_KEYS.items()})
-    if arch.family != "dense" or arch.window or arch.moe is not None:
-        raise ValueError(f"{cfg['name']}: the benchmark's reference covers "
-                         f"the dense block only, not {arch.family}")
-    return arch
 
 
 def build_model(cfg: dict, kv_quant: bool = False):
@@ -51,12 +55,9 @@ def build_model(cfg: dict, kv_quant: bool = False):
 
 
 def program_params(model, w: dict) -> dict:
-    """The benchmark's weights in the program's tree (the same arrays,
-    regrouped; nothing is copied).  Shapes and types are checked against
-    the program's own abstract parameters."""
-    import jax
-
-    tree = {
+    """The dense block's weights in the program's tree (the same arrays,
+    regrouped; nothing is copied), checked by :func:`fit`."""
+    return fit(model, {
         "embed": w["embed"], "lnf": w["lnf"], "head": w["head"],
         "stacks": {"blocks": {"l0": {
             "attn": {"ln": w["ln_attn"], "wq": w["wq"], "wk": w["wk"],
@@ -64,7 +65,14 @@ def program_params(model, w: dict) -> dict:
             "ffn": {"ln": w["ln_mlp"], "w1": w["w1"], "w3": w["w3"],
                     "w2": w["w2"]},
         }}},
-    }
+    })
+
+
+def fit(model, tree: dict) -> dict:
+    """``tree``, after checking its structure, shapes and types against
+    the program's own abstract parameters."""
+    import jax
+
     want = model.abstract_params()
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
     if jax.tree.structure(want) != jax.tree.structure(got) or any(

@@ -1,10 +1,15 @@
 """Finds a cell's parts by name, so that a cell, configuration, traffic
-mix or metric is added by adding files alone.
+mix or metric is added by adding files alone, and so is a configuration
+of a new architecture, with its block.
 
   BENCHMARK.json            cells (``workloads``) and metrics
   bench/cells/<cell>.json   load (loop, rate or concurrency), window
                             tail, and the correctness sample and limit
-  bench/configs/<config>.json   widths, engine settings, provenance
+  bench/configs/<config>.json   widths, engine settings, provenance, and
+                            the name of its block
+  bench/blocks/<block>.py   weights, program mapping, reference and work
+                            counts of one kind of model (the contract is
+                            bench/blocks/__init__.py's docstring)
   bench/traffic/<mix>.json  parameters for bench/traffic.py
   bench/metrics/<metric>.py ``read(ctx)`` -> a number, or None
 """
@@ -56,11 +61,33 @@ def metrics_for(spec: dict, cell_name: str, per_layer: bool) -> list:
             if cell_name in m.get("workloads", [cell_name])]
 
 
-def reader(metric: str, base: Path = BENCH):
-    """The ``read`` function of bench/metrics/<metric>.py."""
-    path = base / "metrics" / f"{metric}.py"
+def _load(kind: str, name: str, path: Path):
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str, base: Path = BENCH):
+    """The ``read`` function of bench/metrics/<metric>.py."""
+    return _load("metric", metric, base / "metrics" / f"{metric}.py").read
+
+
+def block(name: str, base: Path = BENCH):
+    """The module ``blocks/<name>.py`` under ``base``, or else among the
+    benchmark's own blocks, after checking that it gives every function
+    of the contract (``bench.blocks.CONTRACT``)."""
+    from bench.blocks import CONTRACT
+
+    paths = list(dict.fromkeys([base / "blocks" / f"{name}.py",
+                                BENCH / "blocks" / f"{name}.py"]))
+    path = next((p for p in paths if p.is_file()), None)
+    if path is None:
+        raise FileNotFoundError(f"block {name!r}: no file "
+                                + " or ".join(map(str, paths)))
+    mod = _load("block", name, path)
+    missing = [f for f in CONTRACT if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"block {name!r} ({path}) lacks {missing}")
+    return mod

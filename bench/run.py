@@ -3,20 +3,21 @@
   python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell (BENCHMARK.json ``workloads``) is one configuration under one
-traffic mix.  The run makes the weights from the seed on the device,
-builds the program's HTTP server over one SiPipe engine, warms up every
-shape the cell's engine settings can produce, and starts a load
-generator in a child process that streams requests over HTTP.  A
-closed loop starts the cell's ``ramp_s`` before the window, so that the
-engine's seats are full when it opens; set-up ends at the opening.  The
-window lasts ``--seconds``, and the streams still open are read for the
-cell's tail.
+traffic mix, and the configuration names its block
+(``bench/blocks/<name>.py``).  The run makes the block's weights from
+the seed on the device, builds the program's HTTP server over one
+SiPipe engine, warms up every shape the cell's engine settings can
+produce, and starts a load generator in a child process that streams
+requests over HTTP.  A closed loop starts the cell's ``ramp_s`` before
+the window, so that the engine's seats are full when it opens; set-up
+ends at the opening.  The window lasts ``--seconds``, and the streams
+still open are read for the cell's tail.
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` runs
 the same window under the profiler and reports its per-layer metrics.
 After the window the program is freed and a sample of the finished
-requests is checked against the plain float32 reference; the numbers
-compared are printed beside their limits, last on standard error and
-last in the result line.
+requests is checked against the block's plain float32 reference; the
+numbers compared are printed beside their limits, last on standard
+error and last in the result line.
 
 The run exits non-zero, printing no result, when JAX finds no
 accelerator, fewer chips than the cell asks for, or a device that is
@@ -273,17 +274,15 @@ def state_below_dtype(eng, dtype: str) -> int:
                for a in jax.tree.leaves((w.stage.params, w.cache)))
 
 
-def check(cfg: dict, cell: dict, seed: int, picked: list, reqs: list,
-          window_compiles: int, state_leaves: int) -> tuple:
-    """Compares the sample with the reference; (correct, numbers)."""
-    from bench import reference, weights
-
+def check(cfg: dict, block, cell: dict, seed: int, picked: list,
+          reqs: list, window_compiles: int, state_leaves: int) -> tuple:
+    """Compares the sample with the block's reference; (correct,
+    numbers)."""
     t0 = time.monotonic()
-    w = weights.make(cfg, seed)
+    w = block.make(cfg, seed)
     worst, n_tok = 0.0, 0
     for r in picked:
-        g = reference.served_gaps(w, cfg, reqs[r["i"]]["prompt"],
-                                  r["tokens"])
+        g = block.served_gaps(w, cfg, reqs[r["i"]]["prompt"], r["tokens"])
         worst = max(worst, float(g.max()))
         n_tok += len(g)
     del w
@@ -301,18 +300,19 @@ def check(cfg: dict, cell: dict, seed: int, picked: list, reqs: list,
     return correct, numbers
 
 
-def build(cfg: dict, seed: int, kv_quant: bool = False, warm: bool = True):
-    """Weights from the seed, the server over one engine, every stage
-    shape warmed (unless ``warm`` is off), the server started.  Returns
-    (server, engine, stage steps warmed)."""
-    from bench import program, weights
+def build(cfg: dict, block, seed: int, kv_quant: bool = False,
+          warm: bool = True):
+    """The block's weights from the seed, the server over one engine,
+    every stage shape warmed (unless ``warm`` is off), the server
+    started.  Returns (server, engine, stage steps warmed)."""
+    from bench import program
 
-    _, model = program.build_model(cfg, kv_quant=kv_quant)
-    w = weights.make(cfg, seed)
-    server, eng = program.build_server(cfg, model,
-                                       program.program_params(model, w),
-                                       seed)
-    del w          # the engine holds the stage slices; drop the full tree
+    _, model = block.program_model(cfg, kv_quant=kv_quant)
+    # no name here holds the weights: while the engine cuts its stages
+    # from them, the program alone decides how long the full tree lives
+    server, eng = program.build_server(
+        cfg, model, block.program_params(model, block.make(cfg, seed)),
+        seed)
     gc.collect()
     n_warm = warm_up(cfg, eng) if warm else 0
     server.start()
@@ -416,20 +416,19 @@ def run(name: str, seed: int, seconds: float, trace: bool, device=None,
         peaks: dict = None, spec: dict = None,
         base: Path = registry.BENCH) -> dict:
     """One run of cell ``name``; returns the result object.  ``base`` is
-    where its cell, configuration and mix files are found."""
+    where its cell, configuration, block and mix files are found."""
     import jax
-
-    from bench import weights
 
     spec = spec or registry.benchmark()
     wl = registry.workload(name, spec)
     cell = registry.cell(name, base)
     cfg = registry.config(wl["config"], base)
+    block = registry.block(cfg["block"], base)
     mix = registry.traffic(wl["traffic"], base)
     device = device or jax.devices()[0]
     counter = CompileCounter()
 
-    server, eng, n_warm = build(cfg, seed)
+    server, eng, n_warm = build(cfg, block, seed)
     steps: list = []
     if trace:
         instrument(eng, steps)
@@ -445,8 +444,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, device=None,
     log(f"after teardown {sum(a.nbytes for a in jax.live_arrays())} bytes "
         f"of arrays live; peak {peak}")
     t0, t1 = ctx["t0"], ctx["t1"]
-    ctx.update(dims=weights.dims(cfg), peaks=peaks, steps=steps, cfg=cfg,
-               cell=cell)
+    ctx.update(dims=block.dims(cfg), block=block, peaks=peaks, steps=steps,
+               cfg=cfg, cell=cell)
     if trace:
         from bench import trace as tr
 
@@ -480,8 +479,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, device=None,
           flush=True)
 
     picked = sample(records, cell["check"], seed)
-    correct, numbers = check(cfg, cell, seed, picked, reqs, counter.n,
-                             state_leaves)
+    correct, numbers = check(cfg, block, cell, seed, picked, reqs,
+                             counter.n, state_leaves)
     result = {"correct": correct, "attempted": attempted, "failed": failed,
               "metrics": metrics,
               "device": {"platform": device.platform,

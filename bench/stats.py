@@ -61,17 +61,15 @@ def steps_in_window(ctx, kind=None) -> list:
 
 
 def step_mfu(ctx):
-    """Model FLOPs the window's stage steps required (valid tokens only;
-    the LM head on sampled rows only), over the traced window times the
-    chip's peak, in percent.  None without a trace."""
-    from bench import flops
-
+    """Model FLOPs the window's stage steps required (the block's
+    ``step_flops``: valid tokens only, the LM head on sampled rows
+    only), over the traced window times the chip's peak, in percent.
+    None without a trace."""
     if "events" not in ctx:
         return None
-    m, total = ctx["dims"], 0
+    m, block, total = ctx["dims"], ctx["block"], 0
     for s in steps_in_window(ctx):
-        total += flops.step_flops(m, s["layers"], s["tokens"], s["ctx_sum"],
-                                  s["sampled"] if s["last"] else 0)
+        total += block.step_flops(m, s)
     window = (ctx["trace_hi"] - ctx["trace_lo"]) / 1e9
     if not total or window <= 0:
         return None

@@ -207,11 +207,10 @@ def _renamed(events):
 
 def _readings(events):
     """What the existing trace readers and reductions read."""
-    from bench import weights
-
     lo, hi = trace.window(events)
     hi = min(hi, lo + 100 * MS)
-    dims = weights.dims(registry.config("glm4-9b-10l"))
+    cfg = registry.config("glm4-9b-10l")
+    block = registry.block(cfg["block"])
     peaks = registry.load_json(registry.BENCH / "peaks.json")["devices"][
         "TPU v5 lite"]
     steps = [dict(t0=1.0, stage=i, kind=k, batch=16, width=w, tokens=t,
@@ -219,8 +218,9 @@ def _readings(events):
                   sampled=16)
              for i in (0, 1) for k, w, t in (("decode", 1, 16),
                                               ("chunk", 256, 256))]
-    ctx = {"events": events, "trace_lo": lo, "trace_hi": hi, "dims": dims,
-           "peaks": peaks, "steps": steps, "t0": 0.0, "t1": 2.0}
+    ctx = {"events": events, "trace_lo": lo, "trace_hi": hi,
+           "dims": block.dims(cfg), "block": block, "peaks": peaks,
+           "steps": steps, "t0": 0.0, "t1": 2.0}
     out = {name: registry.reader(name)(ctx) for name in (
         "step_mfu.batch", "step_mfu.chat", "decode_hbm_share",
         "span_attn_roofline")}

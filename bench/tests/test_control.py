@@ -17,6 +17,7 @@ SPEC = registry.load_json(BASE / "benchmark.json")
 PEAKS = registry.load_json(registry.BENCH / "peaks.json")["devices"][
     "TPU v5 lite"]
 CFG = registry.config("tiny", BASE)
+BLOCK = registry.block(CFG["block"], BASE)
 LIMIT = registry.cell("tiny.chat", BASE)["check"]["logit_gap_limit"]
 
 
@@ -54,12 +55,12 @@ def test_reference_float8_control_fails_the_limit():
         done = {s.seq_id: s for s in eng.run()}
         served = [{"i": i, "tokens": done[rid].output_ids}
                   for i, rid in enumerate(ids)]
-        ok, nums = run.check(CFG, cell, seed, served, reqs, 0, 0)
+        ok, nums = run.check(CFG, BLOCK, cell, seed, served, reqs, 0, 0)
         assert ok, nums
         ctrl = [dict(r, tokens=reference.control_decode(
             w, CFG, reqs[r["i"]]["prompt"], len(r["tokens"])))
             for r in served]
-        ok, nums = run.check(CFG, cell, seed, ctrl, reqs, 0, 0)
+        ok, nums = run.check(CFG, BLOCK, cell, seed, ctrl, reqs, 0, 0)
         assert not ok, nums
         assert nums["logit_gap_max"]["value"] > LIMIT
         for r in served:

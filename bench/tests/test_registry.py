@@ -11,6 +11,14 @@ def test_every_named_part_has_its_file():
     for c in spec["configs"]:
         assert (registry.ROOT / c["file"]).is_file()
         assert registry.config(c["name"])["name"] == c["name"]
+    # every configuration file, the tests' own too, names a block whose
+    # file gives the whole contract
+    for path in [*(registry.BENCH / "configs").glob("*.json"),
+                 *(registry.BENCH / "tests" / "data" / "configs").glob(
+                     "*.json")]:
+        name = registry.load_json(path)["block"]
+        assert (registry.BENCH / "blocks" / f"{name}.py").is_file(), path
+        registry.block(name)
     for w in spec["workloads"]:
         assert registry.cell(w["name"])["check"]["logit_gap_limit"] > 0
         registry.traffic(w["traffic"])
